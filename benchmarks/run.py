@@ -28,6 +28,7 @@ Run:  PYTHONPATH=src:. python benchmarks/run.py
 from __future__ import annotations
 
 from benchmarks import sections
+from repro import api
 
 # importing a benchmark module registers its sections; this order is the
 # output order
@@ -43,6 +44,7 @@ from benchmarks import adversarial_bench   # noqa: F401  adversarial (BENCH_adve
 
 
 def main() -> None:
+    api.use_compile_cache()
     sections.emit_all()
 
 

@@ -114,6 +114,7 @@ __all__ = [
     "TelemetryFrame",
     "Trace",
     "Tracer",
+    "use_compile_cache",
     "validate_exposition",
 ]
 
@@ -172,6 +173,29 @@ def default_fleet():
 
         _DEFAULT_FLEET = FleetRunner()
     return _DEFAULT_FLEET
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, names the directory (JAX
+    reads it at import) and nothing else is set here.  Otherwise the cache
+    lives at ``<checkout>/.jax_cache``: a fixed path, so a later process
+    on the same checkout finds what an earlier one compiled.  Entry points
+    call this before their first compile; importing the package never
+    does, so tests run with the cache off.
+    """
+    import os
+
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.abspath(os.path.join(
+            os.path.dirname(__file__), "..", "..", ".jax_cache"))
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,8 @@ class StreamRun:
     name: str
     bins: List[int] = dataclasses.field(default_factory=list)
     rscores: List[float] = dataclasses.field(default_factory=list)
+    #: partitions whose consumer changed (present in both iterations)
+    migrations: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def average_rscore(self) -> float:  # Eq. 13
@@ -70,6 +72,9 @@ def run_stream(
             runs[name].rscores.append(
                 rscore(prev[name], res.pid_to_bin, speeds, capacity,
                        active=None if active is None else set(speeds)))
+            runs[name].migrations.append(sum(
+                1 for p, c in res.pid_to_bin.items()
+                if p in prev[name] and prev[name][p] != c))
             prev[name] = res.pid_to_bin
     return runs
 

@@ -27,9 +27,11 @@ module turns that into a production execution layer:
 
 Caveat: the stochastic ANNEAL policies draw their Gumbel noise over a
 ``(chains, N * M)`` plane, so *padding* N changes the PRNG stream and
-therefore the (still valid) trajectories; padding is bit-exact for every
-deterministic policy (all 12 packers and both reactive baselines), and
-*sharding* is bit-exact for every policy, stochastic or not.
+therefore the (still valid) trajectories.  For every deterministic
+policy (all 12 packers and both reactive baselines) padding, and for
+every policy sharding, changes no decision; float fields agree to
+rounding (``repro.lagsim.metrics.agrees``), since programs of other
+shapes sum in other orders.
 """
 from __future__ import annotations
 
@@ -126,7 +128,7 @@ class FleetLagResult:
     #: axis, numpy leaves) plus the per-scenario *resolved*
     #: ``SketchConfig`` (``hist_max`` filled at the scenario's true N) --
     #: padded bucket steps are valid-gated out, so a padded scenario's
-    #: state is bit-identical to a direct ``simulate_lag`` run's
+    #: state agrees with a direct ``simulate_lag`` run's
     sketch: Optional[List[SketchState]] = None
     sketch_configs: Optional[List[SketchConfig]] = None
     #: per-scenario final alert states (leading ``[P]``); see
@@ -573,9 +575,9 @@ class FleetRunner:
         ``cfg.fused_steps`` / ``cfg.fused_kernel`` (the multi-step fused
         path, ``repro.lagsim.fused``) ride the same resolved config, so
         fused and unfused runs never share an executable and a padded
-        fused run equals the direct one bit-for-bit; an N-padded bucket
-        above ``FUSED_MAX_PARTITIONS`` falls back to the per-step scan
-        inside the same program, which is equally exact.
+        fused run agrees with the direct one; an N-padded bucket above
+        ``FUSED_MAX_PARTITIONS`` falls back to the per-step scan inside
+        the same program.
         With ``cfg.telemetry`` on, the result carries one recorder frame
         per scenario (``FleetLagResult.telemetry``), sliced to true
         length like every other trajectory.  Streaming sketches/alerts

@@ -4,11 +4,10 @@ The packer's inner operation -- "given bin loads and an item, pick the
 first/best/worst bin it fits in" -- is a masked argmin/argmax reduction.
 Evaluating algorithm sweeps (12 algorithms x 6 deltas x 500 iterations x
 batches of streams) on device makes this the hot loop, so the kernel grid
-carries an explicit *batch* dimension: each program instance reduces a
-whole ``(rows, M)`` tile of (loads, item) instances for one stream of the
-batch, with the loads tile resident in VMEM.  ``grid = (B, ceil(N/rows))``
-and both dimensions are parallel, so one launch covers the entire
-``f32[B, N, M]`` sweep.
+carries an explicit *batch* dimension: each program instance reduces
+``(rows, M)`` tiles of (loads, item) instances for up to 8 streams of the
+batch, with the loads tiles resident in VMEM.  Both grid dimensions are
+parallel, so one launch covers the entire ``f32[B, N, M]`` sweep.
 
 Semantics match ``repro.core.jaxpack._select_slot``: ties break to the
 lowest slot, an item "fits" iff load + w <= capacity and slot < k.
@@ -33,8 +32,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.telemetry.spans import span as _span
 
-from ._compat import CompilerParams as _CompilerParams
 from ._compat import default_interpret as _default_interpret
+from ._compat import pad_rows as _pad_rows
+from ._compat import row_tile as _row_tile
 
 _BIG = 3.4e38  # python literal: jnp scalars would be captured as consts
 
@@ -43,34 +43,41 @@ NEG = -1       # "inactive instance": the item does not exist, no slot at all
 
 
 def _select_tile_kernel(loads_ref, w_ref, k_ref, cap_ref, *rest, strategy: str,
-                        m: int, rows: int, masked: bool):
-    """One (rows, M) tile: row-wise masked argmin/argmax along the M axis."""
+                        m: int, rows: int, streams: int, masked: bool):
+    """``streams`` x ``(rows, M)`` tiles: row-wise masked argmin/argmax
+    along the M axis, ties to the lowest slot (a double min).  The
+    per-instance rows are transposed once into ``(rows, streams)`` so
+    each stream's instances are a column against its load tile."""
+    slot_ref = rest[-1]
+    w_t, k_t, cap_t = w_ref[...].T, k_ref[...].T, cap_ref[...].T
     if masked:
-        active_ref, slot_ref = rest
-    else:
-        (slot_ref,) = rest
-    loads = loads_ref[0]                              # (rows, M)
-    w = w_ref[0][:, None]                             # (rows, 1)
-    k = k_ref[0][:, None]                             # (rows, 1)
-    cap = cap_ref[0][:, None]
+        act_t = rest[0][...].T
     idx = jax.lax.broadcasted_iota(jnp.int32, (rows, m), 1)
-    fits = (idx < k) & (loads + w <= cap)
-    if strategy == "first":
-        score = jnp.where(fits, idx.astype(jnp.float32), _BIG)
-        best = jnp.argmin(score, axis=1)
-    elif strategy == "best":      # tightest fit = max load; first on tie
-        score = jnp.where(fits, loads, -_BIG)
-        best = jnp.argmax(score, axis=1)
-    elif strategy == "worst":     # most slack = min load; first on tie
-        score = jnp.where(fits, loads, _BIG)
-        best = jnp.argmin(score, axis=1)
-    else:
-        raise ValueError(strategy)
-    found = jnp.any(fits, axis=1)
-    slot = jnp.where(found, best.astype(jnp.int32), jnp.int32(m))
-    if masked:
-        slot = jnp.where(active_ref[0] > 0, slot, jnp.int32(NEG))
-    slot_ref[0] = slot
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, streams), 1)
+    plane = lambda x: jnp.broadcast_to(x, (rows, m))
+    slots = jnp.zeros((rows, streams), jnp.int32)
+    for r in range(streams):
+        col = slice(r, r + 1)
+        loads = loads_ref[r]                              # (rows, M)
+        fits = ((idx < plane(k_t[:, col]))
+                & (loads + plane(w_t[:, col]) <= plane(cap_t[:, col])))
+        if strategy == "first":
+            score = jnp.where(fits, idx.astype(jnp.float32), _BIG)
+        elif strategy == "best":      # tightest fit = max load; first on tie
+            score = jnp.where(fits, -loads, _BIG)
+        elif strategy == "worst":     # most slack = min load; first on tie
+            score = jnp.where(fits, loads, _BIG)
+        else:
+            raise ValueError(strategy)
+        low = jnp.min(score, axis=1, keepdims=True)
+        best = jnp.min(jnp.where(score == low, idx, m), axis=1,
+                       keepdims=True)
+        found = jnp.max(fits.astype(jnp.int32), axis=1, keepdims=True) > 0
+        slot = jnp.where(found, best, jnp.int32(m))       # (rows, 1)
+        if masked:
+            slot = jnp.where(act_t[:, col] > 0, slot, jnp.int32(NEG))
+        slots = jnp.where(lane == r, slot, slots)
+    slot_ref[...] = slots.T
 
 
 def select_slot_grid(loads, w, k, capacity, *, active=None,
@@ -83,51 +90,47 @@ def select_slot_grid(loads, w, k, capacity, *, active=None,
     (bins created); active: optional (B, N) i32/bool -- 0 marks an
     instance whose item does not exist.  Returns (B, N) i32 chosen slot
     per instance (M when nothing fits, ``NEG`` when inactive).  One kernel
-    launch; ``grid = (B, ceil(N / row_tile))``.
+    launch; ``grid = (ceil(B / streams), ceil(N / rows))`` with
+    ``streams = row_tile(B)`` and ``rows`` the whole N or ``row_tile``
+    rounded up to a lane multiple (128).
     """
     if interpret is None:
         interpret = _default_interpret()
     masked = active is not None
     b, n, m = loads.shape
-    rows = min(row_tile, n)
+    lane_tile = -(-row_tile // 128) * 128
+    rows = n if n <= lane_tile else lane_tile
+    streams = _row_tile(b)
     pad = (-n) % rows
-    if pad:
-        # padded rows see k=0 -> nothing fits; their output is sliced off
-        loads = jnp.pad(loads, ((0, 0), (0, pad), (0, 0)))
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-        k = jnp.pad(k, ((0, 0), (0, pad)))
-        capacity = jnp.pad(capacity, ((0, 0), (0, pad)))
-        if masked:
-            active = jnp.pad(active.astype(jnp.int32), ((0, 0), (0, pad)))
-    n_pad = n + pad
-    kernel = functools.partial(_select_tile_kernel, strategy=strategy, m=m,
-                               rows=rows, masked=masked)
-    row_spec = pl.BlockSpec((1, rows), lambda i, j: (i, j))
-    in_specs = [
-        pl.BlockSpec((1, rows, m), lambda i, j: (i, j, 0)),
-        row_spec, row_spec, row_spec,
-    ]
+    # padded instances see k=0 -> nothing fits; their output is sliced off
     args = [loads.astype(jnp.float32), w.astype(jnp.float32),
             k.astype(jnp.int32), capacity.astype(jnp.float32)]
     if masked:
-        in_specs.append(row_spec)
         args.append(active.astype(jnp.int32))
+    pad_n = lambda a: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+    args = [_pad_rows(pad_n(a), streams) for a in args]
+    b_pad, n_pad = args[1].shape
+    kernel = functools.partial(_select_tile_kernel, strategy=strategy, m=m,
+                               rows=rows, streams=streams, masked=masked)
+    row_spec = pl.BlockSpec((streams, rows), lambda i, j: (i, j))
+    in_specs = [pl.BlockSpec((streams, rows, m), lambda i, j: (i, j, 0))]
+    in_specs += [row_spec] * (len(args) - 1)
     call = pl.pallas_call(
         kernel,
-        grid=(b, n_pad // rows),
+        grid=(b_pad // streams, n_pad // rows),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n_pad), jnp.int32),
-        compiler_params=_CompilerParams(
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((b_pad, n_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )
     if isinstance(loads, jax.core.Tracer):
         # under a jit trace the launch is timed by the caller's spans
-        return call(*args)[:, :n]
+        return call(*args)[:b, :n]
     with _span("kernel.select_slot", batch=b, n=n, m=m, strategy=strategy,
                interpret=bool(interpret)):
-        return call(*args)[:, :n]
+        return call(*args)[:b, :n]
 
 
 def select_slot_batch(loads, w, k, capacity, *, active=None,

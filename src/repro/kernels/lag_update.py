@@ -11,8 +11,8 @@ One simulated step of the lag digital twin (``repro.lagsim``) is:
 
 Steps 2-3 are a one-hot segment reduction plus a gather -- the hot inner
 loop when the twin sweeps hundreds of scenarios -- so the kernel fuses all
-three into a single VMEM pass per stream: ``grid = (B,)``, each program
-instance owns one stream's ``(N,)`` state and reduces over the ``(N, M)``
+three into a single VMEM pass: each program instance owns an 8-stream
+tile of ``(N,)`` states and reduces over each stream's ``(M, N)``
 one-hot plane in registers.  Partitions that are unreadable (mid-migration
 downtime, ``readable == 0``) or unassigned (``assign < 0``) keep their
 backlog untouched.
@@ -34,11 +34,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.telemetry.spans import span as _span
 
-from ._compat import CompilerParams as _CompilerParams
 from ._compat import default_interpret as _default_interpret
+from ._compat import pad_rows as _pad_rows
+from ._compat import row_tile as _row_tile
 
 _TINY = 1e-30   # python literal so it is not captured as a traced const
 
@@ -73,53 +75,37 @@ def lag_update_reference(lag, produced, assign, readable, cap, *, m: int,
     return out
 
 
-def _drain_math(avail, assign, live, cap, *, n: int, m: int):
-    """The fused segment-sum + proportional drain on one stream's (N,)
-    values -- shared by the batched and the rank-1 kernel entries."""
-    live = live & (assign >= 0)
-    names = jax.lax.broadcasted_iota(jnp.int32, (n, m), 1)
-    onehot = (assign[:, None] == names) & live[:, None]    # (N, M)
-    per_bin = jnp.sum(jnp.where(onehot, avail[:, None], 0.0), axis=0)  # (M,)
-    ratio = jnp.minimum(1.0, cap / jnp.maximum(per_bin, _TINY))
-    frac = jnp.sum(jnp.where(onehot, ratio[None, :], 0.0), axis=1)     # (N,)
-    return jnp.maximum(avail * (1.0 - frac), 0.0)
-
-
 def _lag_update_kernel(lag_ref, prod_ref, assign_ref, readable_ref, cap_ref,
-                       *rest, n: int, m: int, masked: bool):
-    """One stream: fused produce + one-hot segment drain over (N, M)."""
-    if masked:
-        active_ref, out_ref = rest
-        act = active_ref[0] > 0
-        avail = lag_ref[0] + jnp.where(act, prod_ref[0], 0.0)   # (N,)
-        live = (readable_ref[0] > 0) & act
-    else:
-        (out_ref,) = rest
-        avail = lag_ref[0] + prod_ref[0]                       # (N,)
-        live = readable_ref[0] > 0
-    out = _drain_math(avail, assign_ref[0], live, cap_ref[0], n=n, m=m)
-    if masked:
-        out = jnp.where(act, out, 0.0)
-    out_ref[0] = out
+                       *rest, rows: int, n: int, m: int, masked: bool):
+    """``rows`` streams: fused produce + one-hot segment drain.
 
-
-def _lag_update_kernel_1d(lag_ref, prod_ref, assign_ref, readable_ref,
-                          cap_ref, *rest, n: int, m: int, masked: bool):
-    """Rank-1 twin of ``_lag_update_kernel``: refs are the (N,)/(M,)
-    arrays themselves, no leading stream axis to index away."""
-    if masked:
-        active_ref, out_ref = rest
-        act = active_ref[...] > 0
-        avail = lag_ref[...] + jnp.where(act, prod_ref[...], 0.0)
-        live = (readable_ref[...] > 0) & act
-    else:
-        (out_ref,) = rest
-        avail = lag_ref[...] + prod_ref[...]
-        live = readable_ref[...] > 0
-    out = _drain_math(avail, assign_ref[...], live, cap_ref[...], n=n, m=m)
-    if masked:
-        out = jnp.where(act, out, 0.0)
-    out_ref[...] = out
+    Each stream's one-hot plane is laid out ``(M, N)`` -- bins on
+    sublanes, partitions on lanes -- so the ``(1, N)`` state rows
+    broadcast into it directly; only the per-bin budget is turned into a
+    ``(M, 1)`` column (one block transpose per grid step)."""
+    out_ref = rest[-1]
+    names = jax.lax.broadcasted_iota(jnp.int32, (m, n), 0)
+    cap_t = cap_ref[...].T                                  # (M, rows)
+    for r in range(rows):
+        row = slice(r, r + 1)
+        live = readable_ref[row, :] > 0                     # (1, N)
+        produced = prod_ref[row, :]
+        if masked:
+            act = rest[0][row, :] > 0
+            produced = jnp.where(act, produced, 0.0)
+            live = live & act
+        avail = lag_ref[row, :] + produced
+        assign = assign_ref[row, :]
+        onehot = (assign == names) & live                   # (M, N)
+        per_bin = jnp.sum(jnp.where(onehot, avail, 0.0), axis=1,
+                          keepdims=True)                    # (M, 1)
+        ratio = jnp.minimum(1.0, cap_t[:, row] / jnp.maximum(per_bin, _TINY))
+        frac = jnp.sum(jnp.where(onehot, ratio, 0.0), axis=0,
+                       keepdims=True)                       # (1, N)
+        out = jnp.maximum(avail * (1.0 - frac), 0.0)
+        if masked:
+            out = jnp.where(act, out, 0.0)
+        out_ref[row, :] = out
 
 
 def lag_update_batch(lag, produced, assign, readable, cap, *, active=None,
@@ -131,40 +117,46 @@ def lag_update_batch(lag, produced, assign, readable, cap, *, active=None,
     drain budget for the step; active: optional i32/bool[B, N] partition
     mask (0 = the partition does not exist: no production, no drain, lag
     forced to 0).  Returns f32[B, N] post-drain backlog.
-    ``grid = (B,)``; each instance holds one stream's (N,) state plus the
-    (N, M) one-hot plane in VMEM.
+    ``grid = (ceil(B / rows),)`` with ``rows = row_tile(B)``: each
+    instance holds ``rows`` streams' state and builds one stream's
+    ``(M, N)`` one-hot plane at a time in VMEM.
     """
     if interpret is None:
         interpret = _default_interpret()
     masked = active is not None
     b, n = lag.shape
     m = cap.shape[1]
-    kernel = functools.partial(_lag_update_kernel, n=n, m=m, masked=masked)
-    n_spec = pl.BlockSpec((1, n), lambda i: (i, 0))
+    rows = _row_tile(b)
+    kernel = functools.partial(_lag_update_kernel, rows=rows, n=n, m=m,
+                               masked=masked)
+    n_spec = pl.BlockSpec((rows, n), lambda i: (i, 0))
     in_specs = [n_spec, n_spec, n_spec, n_spec,
-                pl.BlockSpec((1, m), lambda i: (i, 0))]
+                pl.BlockSpec((rows, m), lambda i: (i, 0))]
     args = [lag.astype(jnp.float32), produced.astype(jnp.float32),
             assign.astype(jnp.int32), readable.astype(jnp.int32),
             cap.astype(jnp.float32)]
     if masked:
         in_specs.append(n_spec)
         args.append(active.astype(jnp.int32))
+    args = [_pad_rows(a, rows) for a in args]
+    b_pad = args[0].shape[0]
     call = pl.pallas_call(
         kernel,
-        grid=(b,),
+        grid=(b_pad // rows,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        out_specs=n_spec,
+        out_shape=jax.ShapeDtypeStruct((b_pad, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )
     if isinstance(lag, jax.core.Tracer):
         # inside a jit trace: launch cost belongs to the enclosing
         # fleet.compile / fleet.dispatch spans, not a per-step host span
-        return call(*args)
+        return call(*args)[:b]
     with _span("kernel.lag_update", batch=b, n=n, m=m,
                interpret=bool(interpret)):
-        return call(*args)
+        return call(*args)[:b]
 
 
 def lag_update_single(lag, produced, assign, readable, cap, *, active=None,
@@ -172,30 +164,11 @@ def lag_update_single(lag, produced, assign, readable, cap, *, active=None,
     """Rank-1 fused lag update: one stream, no batch axis.
 
     lag, produced: f32[N]; assign: i32[N]; readable: i32[N]; cap: f32[M];
-    active: optional i32/bool[N].  Returns f32[N].  Same semantics as one
-    row of ``lag_update_batch`` (both are pinned to
-    ``lag_update_reference``), but callers with rank-1 state -- the lag
-    engine's per-step ``drain`` inside ``lax.scan`` -- skip the
-    ``lag[None]`` expand + ``[0]`` squeeze round-trip per step.
+    active: optional i32/bool[N].  Returns f32[N]: row 0 of a one-stream
+    ``lag_update_batch`` (the lag engine's per-step ``drain`` inside its
+    vmapped ``lax.scan``, where the batching rule adds the stream axis).
     """
-    if interpret is None:
-        interpret = _default_interpret()
-    masked = active is not None
-    n = lag.shape[0]
-    m = cap.shape[0]
-    kernel = functools.partial(_lag_update_kernel_1d, n=n, m=m, masked=masked)
-    args = [lag.astype(jnp.float32), produced.astype(jnp.float32),
-            assign.astype(jnp.int32), readable.astype(jnp.int32),
-            cap.astype(jnp.float32)]
-    if masked:
-        args.append(active.astype(jnp.int32))
-    call = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        interpret=interpret,
-    )
-    if isinstance(lag, jax.core.Tracer):
-        return call(*args)
-    with _span("kernel.lag_update", batch=1, n=n, m=m,
-               interpret=bool(interpret)):
-        return call(*args)
+    return lag_update_batch(
+        lag[None], produced[None], assign[None], readable[None], cap[None],
+        active=None if active is None else active[None],
+        interpret=interpret)[0]

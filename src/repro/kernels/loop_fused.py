@@ -2,15 +2,16 @@
 
 ``kernels/lag_update.py`` fuses ONE step's produce + drain; the scan
 around it still pays a dispatch per simulated step.  This kernel hoists
-the whole loop: ``grid = (B, ceil(T / K))`` with the time dimension
-marked ``"arbitrary"`` (sequential), and each program instance advances
-``K = fused_steps`` steps of one stream while the entire carry -- the
-per-partition backlog, the previous assignment, and the migration
-downtime counters -- stays resident in VMEM scratch across grid steps.
-The ``[1, K, N]`` rate (and active-mask) slabs are streamed per grid
-step through Pallas' pipelined block fetches, so the next block's DMA
-overlaps the current block's compute (double buffering); K tunes slab
-size against pipeline depth.
+the whole loop: ``grid = (ceil(B / 8), ceil(T / K))`` with the time
+dimension marked ``"arbitrary"`` (sequential), and each program instance
+advances ``K = fused_steps`` steps of an 8-stream tile -- streams on
+sublanes, partitions on lanes, every value a 2-D plane -- while the
+entire carry (the per-partition backlog, the previous assignment, and
+the migration downtime counters) stays resident in VMEM scratch across
+grid steps.  The time-major ``[K, 8, N]`` rate (and active-mask) slabs
+are streamed per grid step through Pallas' pipelined block fetches, so
+the next block's DMA overlaps the current block's compute (double
+buffering); K tunes slab size against pipeline depth.
 
 Each in-kernel step replays the heuristic policy families exactly:
 
@@ -24,10 +25,11 @@ Each in-kernel step replays the heuristic policy families exactly:
      ``migration_steps`` steps);
   5. the produce + proportional-drain update of ``lag_update``.
 
-The bit-exact oracle is the XLA fused engine ``repro.lagsim.fused``
-(itself pinned bit-for-bit to the unfused per-step scan), asserted in
-tests/test_fused_loop.py and the CI fused smoke.  Like the other three
-kernels, hosts without a TPU run Pallas interpreter mode automatically.
+Its oracle is the per-step scan of ``repro.lagsim.engine``: decisions
+exact, lag to rounding (``repro.lagsim.metrics.agrees``; the kernel sums
+in its own order), asserted in tests/test_fused_loop.py and the CI fused
+smoke.  Like the other three kernels, hosts without a TPU run Pallas
+interpreter mode automatically.
 
 The int32 name bitmask bounds the kernel to ``n <= 14`` partitions
 (``2n + 1 < 31`` bits) -- the engine falls back to the unfused scan
@@ -45,8 +47,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.telemetry.spans import span as _span
 
-from ._compat import CompilerParams as _CompilerParams
 from ._compat import default_interpret as _default_interpret
+from ._compat import pad_rows as _pad_rows
+from ._compat import row_tile as _row_tile
 
 NEG = -1
 _TINY = 1e-30   # python literal so it is not captured as a traced const
@@ -56,48 +59,56 @@ _STRATEGIES = ("next", "first", "best", "worst")
 def _one_step(speeds, act, lag, prev, down, *, strategy: str,
               decreasing: bool, capacity: float, dt: float, mig: int,
               n: int):
-    """One simulated step on one stream's ``(N,)`` state (pure jnp on
-    kernel-loaded values; see the module docstring for the phases)."""
+    """One simulated step of ``rows`` streams at once: every value is a
+    2-D ``(rows, N)`` / ``(rows, M)`` plane or a ``(rows, 1)`` column
+    (streams on sublanes), and the per-item loops below read columns by
+    static lane slices -- see the module docstring for the phases.
+    ``act`` is the i32 active mask (``None`` when unmasked)."""
+    rows = speeds.shape[0]
     m = n + 1
     inf = jnp.float32(jnp.inf)
     one = jnp.int32(1)
-    iota_n = lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
-    iota_m = lax.broadcasted_iota(jnp.int32, (1, m), 1)[0]
+    iota_n = lax.broadcasted_iota(jnp.int32, (rows, n), 1)
+    iota_m = lax.broadcasted_iota(jnp.int32, (rows, m), 1)
+    col = lambda x, i: x[:, i:i + 1]                      # (rows, 1)
     cap = jnp.float32(capacity)
     cap_step = jnp.float32(capacity * dt)
 
     produced = speeds * jnp.float32(dt)
     if act is not None:
-        produced = jnp.where(act, produced, 0.0)
+        produced = jnp.where(act > 0, produced, 0.0)
 
     # phase 1: traversal order (stable non-increasing sort as a pairwise
     # rank: strictly-greater plus equal-with-lower-index counts)
     if decreasing:
-        col = lax.broadcasted_iota(jnp.int32, (n, n), 1)
-        row = lax.broadcasted_iota(jnp.int32, (n, n), 0)
-        gt = speeds[:, None] < speeds[None, :]
-        eq_lo = (speeds[:, None] == speeds[None, :]) & (col < row)
-        rank = jnp.sum((gt | eq_lo).astype(jnp.int32), axis=1)      # (n,)
-        oh = rank[:, None] == col
-        order = jnp.sum(jnp.where(oh, row, 0), axis=0)
-        sp_ord = jnp.sum(jnp.where(oh, speeds[:, None], 0.0), axis=0)
-        act_ord = (None if act is None else
-                   jnp.sum(jnp.where(oh, act[:, None].astype(jnp.int32), 0),
-                           axis=0) > 0)
+        rank = jnp.zeros((rows, n), jnp.int32)
+        for j in range(n):
+            sj = col(speeds, j)
+            rank = rank + ((sj > speeds)
+                           | ((sj == speeds) & (iota_n > j))).astype(jnp.int32)
+        order = jnp.zeros((rows, n), jnp.int32)
+        sp_ord = jnp.zeros((rows, n), jnp.float32)
+        act_ord = None if act is None else jnp.zeros((rows, n), jnp.int32)
+        for i in range(n):
+            at = col(rank, i) == iota_n             # item i's sorted position
+            order = jnp.where(at, jnp.int32(i), order)
+            sp_ord = jnp.where(at, col(speeds, i), sp_ord)
+            if act is not None:
+                act_ord = jnp.where(at, col(act, i), act_ord)
     else:
         order = iota_n
         sp_ord = speeds
         act_ord = act
 
     # phase 2: slot selection (binpack_select logic, double-min tie-break)
-    loads = jnp.full((m,), inf, jnp.float32)
-    creator = jnp.full((m,), NEG, jnp.int32)
-    slot_of = jnp.full((n,), NEG, jnp.int32)
-    k = jnp.int32(0)
-    lastload = jnp.float32(0.0)
+    loads = jnp.full((rows, m), inf, jnp.float32)
+    creator = jnp.full((rows, m), NEG, jnp.int32)
+    slot_of = jnp.full((rows, n), NEG, jnp.int32)
+    k = jnp.zeros((rows, 1), jnp.int32)
+    lastload = jnp.zeros((rows, 1), jnp.float32)
     for i in range(n):
-        w = sp_ord[i]
-        j = order[i]
+        w = col(sp_ord, i)
+        j = col(order, i)
         d = loads + w
         fits = d <= cap
         if strategy == "next":
@@ -110,22 +121,20 @@ def _one_step(speeds, act, lag, prev, down, *, strategy: str,
                 score = jnp.where(fits, -loads, inf)
             else:
                 score = jnp.where(fits, loads, inf)
-            mn = jnp.min(score)
-            s_sel = jnp.min(jnp.where(score == mn, iota_m, jnp.int32(127)))
+            mn = jnp.min(score, axis=1, keepdims=True)
+            s_sel = jnp.min(jnp.where(score == mn, iota_m, jnp.int32(127)),
+                            axis=1, keepdims=True)
             found = mn < inf
             slot = jnp.where(found, s_sel, k)
-        coh = iota_m == slot
-        if act_ord is None:
-            a = None
-            upd = coh
-        else:
-            a = act_ord[i]
-            upd = coh & a
+        upd = iota_m == slot
+        if act_ord is not None:
+            a = col(act_ord, i) > 0
+            upd = upd & a
         loads = jnp.where(upd, jnp.where(found, d, w), loads)
         creator = jnp.where(upd & ~found, j, creator)
         new_last = jnp.where(found & (slot == k - 1), lastload + w,
                              jnp.where(~found, w, lastload))
-        if a is None:
+        if act_ord is None:
             lastload = new_last
             k = k + (~found).astype(jnp.int32)
             slot_of = jnp.where(iota_n == j, slot, slot_of)
@@ -135,17 +144,16 @@ def _one_step(speeds, act, lag, prev, down, *, strategy: str,
             slot_of = jnp.where((iota_n == j) & a, slot, slot_of)
 
     # phase 3: sticky naming over creation slots (int32 name bitmasks)
-    ohc = creator[:n, None] == iota_n[None, :]
-    pv = jnp.sum(jnp.where(ohc, prev[None, :], 0), axis=1)
-    p_all = jnp.where(creator[:n] >= 0, pv, NEG)
-    claimed = jnp.int32(0)
-    seen = jnp.int32(0)
-    q = jnp.int32(0)
-    new_assign = jnp.full((n,), NEG, jnp.int32)
+    claimed = jnp.zeros((rows, 1), jnp.int32)
+    seen = jnp.zeros((rows, 1), jnp.int32)
+    q = jnp.zeros((rows, 1), jnp.int32)
+    new_assign = jnp.full((rows, n), NEG, jnp.int32)
     for s in range(n):
-        v = p_all[s]
+        c = col(creator, s)                 # item that created slot s
+        pv = jnp.sum(jnp.where(c == iota_n, prev, 0), axis=1, keepdims=True)
+        v = jnp.where(c >= 0, pv, NEG)      # its previous bin name
         vbit = one << jnp.maximum(v, 0)
-        live = jnp.int32(s) < k
+        live = s < k
         cand = (v >= 0) & ((seen & vbit) == 0)
         seen = jnp.where(v >= 0, seen | vbit, seen)
         win = cand & (v >= q) & live
@@ -164,25 +172,32 @@ def _one_step(speeds, act, lag, prev, down, *, strategy: str,
     new_down = jnp.where(moved, jnp.int32(mig), jnp.maximum(down - 1, 0))
     readable = (new_down == 0) & (new_assign >= 0)
     avail = lag + produced
-    live_p = readable & (slot_of >= 0)
-    onehot = (slot_of[:, None] == iota_m[None, :]) & live_p[:, None]
-    per_bin = jnp.sum(jnp.where(onehot, avail[:, None], 0.0), axis=0)
+    slot_live = jnp.where(readable & (slot_of >= 0), slot_of, NEG)
+    per_bin = jnp.zeros((rows, m), jnp.float32)
+    for i in range(n):
+        per_bin = per_bin + jnp.where(col(slot_live, i) == iota_m,
+                                      col(avail, i), 0.0)
     ratio = jnp.minimum(1.0, cap_step / jnp.maximum(per_bin, _TINY))
-    frac = jnp.sum(jnp.where(onehot, ratio[None, :], 0.0), axis=1)
+    frac = jnp.zeros((rows, n), jnp.float32)
+    for i in range(n):
+        f_i = jnp.sum(jnp.where(col(slot_live, i) == iota_m, ratio, 0.0),
+                      axis=1, keepdims=True)
+        frac = jnp.where(iota_n == i, f_i, frac)
     new_lag = jnp.maximum(avail * (1.0 - frac), 0.0)
+    unread = new_down > 0
     if act is not None:
-        new_lag = jnp.where(act, new_lag, 0.0)
-        unread = (new_down > 0) & act
-    else:
-        unread = new_down > 0
+        new_lag = jnp.where(act > 0, new_lag, 0.0)
+        unread = unread & (act > 0)
     return new_lag, new_assign, new_down, k, moved, unread
 
 
 def _loop_fused_kernel(*refs, k_blk: int, n: int, masked: bool,
                        strategy: str, decreasing: bool, capacity: float,
                        dt: float, mig: int):
-    """Advance ``k_blk`` steps of one stream; carry lives in VMEM scratch
-    across the sequential (``"arbitrary"``) time-block grid dimension."""
+    """Advance ``k_blk`` steps of a tile of streams; the carry lives in
+    VMEM scratch across the sequential (``"arbitrary"``) time-block grid
+    dimension.  Time-major refs: ``rates_ref[kk]`` is step ``kk``'s
+    ``(rows, N)`` slab, ``tot_ref[kk]`` its ``(rows, 1)`` column."""
     if masked:
         (rates_ref, active_ref, lag0_ref, tot_ref, mx_ref, cons_ref,
          migs_ref, unread_ref, asg_ref, lag_s, prev_s, down_s) = refs
@@ -193,25 +208,25 @@ def _loop_fused_kernel(*refs, k_blk: int, n: int, masked: bool,
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        lag_s[...] = lag0_ref[0]
-        prev_s[...] = jnp.full((n,), NEG, jnp.int32)
-        down_s[...] = jnp.zeros((n,), jnp.int32)
+        lag_s[...] = lag0_ref[...]
+        prev_s[...] = jnp.full(prev_s.shape, NEG, jnp.int32)
+        down_s[...] = jnp.zeros(down_s.shape, jnp.int32)
 
     lag = lag_s[...]
     prev = prev_s[...]
     down = down_s[...]
     for kk in range(k_blk):
-        speeds = rates_ref[0, kk]
-        act = None if active_ref is None else active_ref[0, kk] > 0
+        act = None if active_ref is None else active_ref[kk]
         lag, prev, down, k, moved, unread = _one_step(
-            speeds, act, lag, prev, down, strategy=strategy,
+            rates_ref[kk], act, lag, prev, down, strategy=strategy,
             decreasing=decreasing, capacity=capacity, dt=dt, mig=mig, n=n)
-        tot_ref[0, kk] = jnp.sum(lag)
-        mx_ref[0, kk] = jnp.max(lag)
-        cons_ref[0, kk] = k
-        migs_ref[0, kk] = jnp.sum(moved.astype(jnp.int32))
-        unread_ref[0, kk] = jnp.sum(unread.astype(jnp.int32))
-        asg_ref[0, kk] = prev
+        tot_ref[kk] = jnp.sum(lag, axis=1, keepdims=True)
+        mx_ref[kk] = jnp.max(lag, axis=1, keepdims=True)
+        cons_ref[kk] = k
+        migs_ref[kk] = jnp.sum(moved.astype(jnp.int32), axis=1, keepdims=True)
+        unread_ref[kk] = jnp.sum(unread.astype(jnp.int32), axis=1,
+                                 keepdims=True)
+        asg_ref[kk] = prev
     lag_s[...] = lag
     prev_s[...] = prev
     down_s[...] = down
@@ -252,63 +267,60 @@ def loop_fused_batch(rates, *, strategy: str, decreasing: bool,
     if interpret is None:
         interpret = _default_interpret()
     masked = active is not None
+    traced = isinstance(rates, jax.core.Tracer)
     t_blocks = -(-t // k_blk)
     t_pad = t_blocks * k_blk
-    rates = jnp.asarray(rates, jnp.float32)
-    if t_pad != t:
-        rates = jnp.pad(rates, ((0, 0), (0, t_pad - t), (0, 0)))
+    rows = _row_tile(b)
+
+    def time_major(x):
+        """[B, T, ...] -> [T_pad, B_pad, ...], zero-padded: padded steps
+        come after every real one (time is causal) and padded streams
+        are independent rows; both are sliced off the outputs."""
+        x = _pad_rows(x, rows)
+        x = jnp.pad(x, [(0, 0), (0, t_pad - t)] + [(0, 0)] * (x.ndim - 2))
+        return jnp.swapaxes(x, 0, 1)
+
+    args = [time_major(jnp.asarray(rates, jnp.float32))]
+    if masked:
+        args.append(time_major(jnp.asarray(active).astype(jnp.int32)))
     if initial_lag is None:
         initial_lag = jnp.zeros((b, n), jnp.float32)
-    else:
-        initial_lag = jnp.asarray(initial_lag, jnp.float32)
+    args.append(_pad_rows(jnp.asarray(initial_lag, jnp.float32), rows))
+    b_pad = args[-1].shape[0]
 
     kernel = functools.partial(
         _loop_fused_kernel, k_blk=k_blk, n=n, masked=masked,
         strategy=strategy, decreasing=bool(decreasing),
         capacity=float(capacity), dt=float(dt), mig=int(migration_steps))
-    slab = pl.BlockSpec((1, k_blk, n), lambda i, j: (i, j, 0))
-    in_specs = [slab]
-    args = [rates]
-    if masked:
-        act = jnp.asarray(active).astype(jnp.int32)
-        if t_pad != t:
-            act = jnp.pad(act, ((0, 0), (0, t_pad - t), (0, 0)))
-        in_specs.append(slab)
-        args.append(act)
-    in_specs.append(pl.BlockSpec((1, n), lambda i, j: (i, 0)))
-    args.append(initial_lag)
-    step_spec = pl.BlockSpec((1, k_blk), lambda i, j: (i, j))
+    slab = pl.BlockSpec((k_blk, rows, n), lambda i, j: (j, i, 0))
+    step_spec = pl.BlockSpec((k_blk, rows, 1), lambda i, j: (j, i, 0))
+    step_shape = lambda dtype: jax.ShapeDtypeStruct((t_pad, b_pad, 1), dtype)
     call = pl.pallas_call(
         kernel,
-        grid=(b, t_blocks),
-        in_specs=in_specs,
-        out_specs=[step_spec, step_spec, step_spec, step_spec, step_spec,
-                   slab],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, t_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, t_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, t_pad), jnp.int32),
-            jax.ShapeDtypeStruct((b, t_pad), jnp.int32),
-            jax.ShapeDtypeStruct((b, t_pad), jnp.int32),
-            jax.ShapeDtypeStruct((b, t_pad, n), jnp.int32),
-        ],
+        grid=(b_pad // rows, t_blocks),
+        in_specs=[slab] * (len(args) - 1)
+        + [pl.BlockSpec((rows, n), lambda i, j: (i, 0))],
+        out_specs=[step_spec] * 5 + [slab],
+        out_shape=[step_shape(jnp.float32), step_shape(jnp.float32),
+                   step_shape(jnp.int32), step_shape(jnp.int32),
+                   step_shape(jnp.int32),
+                   jax.ShapeDtypeStruct((t_pad, b_pad, n), jnp.int32)],
         scratch_shapes=[
-            pltpu.VMEM((n,), jnp.float32),   # lag carry
-            pltpu.VMEM((n,), jnp.int32),     # previous assignment
-            pltpu.VMEM((n,), jnp.int32),     # migration downtime
+            pltpu.VMEM((rows, n), jnp.float32),   # lag carry
+            pltpu.VMEM((rows, n), jnp.int32),     # previous assignment
+            pltpu.VMEM((rows, n), jnp.int32),     # migration downtime
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )
 
     def run(*a):
-        outs = call(*a)
-        if t_pad != t:
-            outs = [o[:, :t] for o in outs]
-        return tuple(outs)
+        *steps, asg = call(*a)
+        steps = [o[:t, :b, 0].T for o in steps]
+        return tuple(steps) + (jnp.swapaxes(asg[:t, :b], 0, 1),)
 
-    if isinstance(rates, jax.core.Tracer):
+    if traced:
         return run(*args)
     with _span("kernel.loop_fused", batch=b, t=t, n=n, k=k_blk,
                interpret=bool(interpret)):

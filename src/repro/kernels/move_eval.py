@@ -11,9 +11,9 @@ its current bin to bin ``b`` -- under the objective
 (the paper's consumer count plus the Eq. 10 R-score weighted by ``lam``).
 That is an ``f32[K, N, M]`` plane per step and the optimizer's hot inner
 loop, so the kernel fuses the whole evaluation into one VMEM pass per
-chain: ``grid = (K,)``, each program instance holds one chain's bin state
-(loads/counts over ``M`` name slots) plus the shared item data and emits
-the full ``(N, M)`` delta tile.  Moves that would violate capacity are
+chain: each program instance holds an 8-chain tile of bin state
+(loads/counts over ``M`` name slots) plus the item data and emits each
+chain's full ``(N, M)`` delta tile.  Moves that would violate capacity are
 masked to ``MOVE_BLOCKED`` (a large finite sentinel); a move is allowed iff
 
     b != assign[p]  and  (loads[b] + w <= C   or
@@ -41,11 +41,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.telemetry.spans import span as _span
 
-from ._compat import CompilerParams as _CompilerParams
 from ._compat import default_interpret as _default_interpret
+from ._compat import pad_rows as _pad_rows
+from ._compat import row_tile as _row_tile
 
 # Large finite sentinel for masked (infeasible) moves.  Finite so that
 # downstream softmax/Gumbel selection arithmetic (-MOVE_BLOCKED / T) stays
@@ -101,35 +103,45 @@ def move_delta_reference(loads, counts, assign, speeds, prev, lam, capacity,
 
 
 def _move_eval_kernel(loads_ref, counts_ref, assign_ref, speeds_ref,
-                      prev_ref, lam_ref, cap_ref, *rest, n: int, m: int,
-                      masked: bool):
-    """One chain: the full (N, M) delta plane in a single VMEM pass."""
+                      prev_ref, ratio_ref, cap_ref, *rest, rows: int, n: int,
+                      m: int, masked: bool):
+    """``rows`` chains: each chain's full (N, M) delta plane in one VMEM
+    pass.  Item rows are transposed once per grid step into ``(N, rows)``
+    so that each chain's items are an ``(N, 1)`` column against its
+    ``(1, M)`` bin rows."""
+    out_ref = rest[-1]
+    assign_t = assign_ref[...].T                          # (N, rows)
+    speeds_t = speeds_ref[...].T
+    prev_t = prev_ref[...].T
     if masked:
-        active_ref, out_ref = rest
-    else:
-        (out_ref,) = rest
-    loads = loads_ref[0]                                  # (M,)
-    counts = counts_ref[0]                                # (M,)
-    assign = assign_ref[0]                                # (N,)
-    speeds = speeds_ref[0]                                # (N,)
-    prev = prev_ref[0]                                    # (N,)
-    lam = lam_ref[0, 0]
-    cap = cap_ref[0, 0]
+        active_t = rest[0][...].T
     names = jax.lax.broadcasted_iota(jnp.int32, (n, m), 1)
-    cur = assign[:, None] == names                        # (N, M) one-hot
-    count_a = jnp.sum(jnp.where(cur, counts[None, :], 0), axis=1)   # (N,)
-    w = speeds[:, None]
-    d_bins = ((counts[None, :] == 0).astype(jnp.float32)
-              - (count_a[:, None] == 1).astype(jnp.float32))
-    sticky = prev >= 0
-    was_moved = ((assign != prev) & sticky).astype(jnp.float32)
-    now_moved = ((names != prev[:, None]) & sticky[:, None]).astype(jnp.float32)
-    d_r = (now_moved - was_moved[:, None]) * w * (lam / cap)
-    allowed = (~cur) & ((loads[None, :] + w <= cap)
-                        | ((counts[None, :] == 0) & (w > cap)))
-    if masked:
-        allowed = allowed & (active_ref[0] > 0)[:, None]
-    out_ref[0] = jnp.where(allowed, d_bins + d_r, MOVE_BLOCKED)
+    # Mosaic broadcasts a value along sublanes or lanes but not both in
+    # one op, so every (1, M) row and (N, 1) column is widened first
+    plane = lambda x: jnp.broadcast_to(x, (n, m))
+    for r in range(rows):
+        row = slice(r, r + 1)
+        loads = plane(loads_ref[row, :])
+        counts = plane(counts_ref[row, :])
+        assign = assign_t[:, row]                         # (N, 1)
+        prev = prev_t[:, row]
+        w = speeds_t[:, row]
+        cap = cap_ref[r, 0]                               # SMEM scalars
+        scale = plane(w * ratio_ref[r, 0])                # w * lam / cap
+        oversized = plane(w > cap)
+        cur = assign == names                             # (N, M) one-hot
+        count_a = jnp.sum(jnp.where(cur, counts, 0), axis=1, keepdims=True)
+        d_bins = ((counts == 0).astype(jnp.float32)
+                  - plane((count_a == 1).astype(jnp.float32)))
+        sticky = prev >= 0                                # (N, 1)
+        was_moved = plane(((assign != prev) & sticky).astype(jnp.float32))
+        now_moved = ((names != prev) & plane(sticky)).astype(jnp.float32)
+        d_r = (now_moved - was_moved) * scale
+        allowed = (~cur) & ((loads + plane(w) <= cap)
+                            | ((counts == 0) & oversized))
+        if masked:
+            allowed = allowed & plane(active_t[:, row] > 0)
+        out_ref[r] = jnp.where(allowed, d_bins + d_r, MOVE_BLOCKED)
 
 
 def move_delta_batch(loads, counts, assign, speeds, prev, lam, cap, *,
@@ -141,38 +153,46 @@ def move_delta_batch(loads, counts, assign, speeds, prev, lam, cap, *,
     optional i32/bool[K, N] item mask (0 = item does not exist, all of
     its moves are blocked).
     Returns f32[K, N, M] move deltas (``MOVE_BLOCKED`` where masked).
-    ``grid = (K,)``; each program instance owns one chain's bin state and
-    its (N, M) delta tile.
+    ``grid = (ceil(K / rows),)`` with ``rows = row_tile(K)``; each
+    program instance owns ``rows`` chains' bin state and their (N, M)
+    delta tiles.
     """
     if interpret is None:
         interpret = _default_interpret()
     masked = active is not None
     k, m = loads.shape
     n = assign.shape[1]
-    kernel = functools.partial(_move_eval_kernel, n=n, m=m, masked=masked)
-    m_spec = pl.BlockSpec((1, m), lambda i: (i, 0))
-    n_spec = pl.BlockSpec((1, n), lambda i: (i, 0))
-    s_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    rows = _row_tile(k)
+    kernel = functools.partial(_move_eval_kernel, rows=rows, n=n, m=m,
+                               masked=masked)
+    m_spec = pl.BlockSpec((rows, m), lambda i: (i, 0))
+    n_spec = pl.BlockSpec((rows, n), lambda i: (i, 0))
+    s_spec = pl.BlockSpec((rows, 1), lambda i: (i, 0),
+                          memory_space=pltpu.SMEM)
     in_specs = [m_spec, m_spec, n_spec, n_spec, n_spec, s_spec, s_spec]
     args = [loads.astype(jnp.float32), counts.astype(jnp.int32),
             assign.astype(jnp.int32), speeds.astype(jnp.float32),
-            prev.astype(jnp.int32), lam.astype(jnp.float32).reshape(k, 1),
+            prev.astype(jnp.int32),
+            (lam.astype(jnp.float32) / cap.astype(jnp.float32)).reshape(k, 1),
             cap.astype(jnp.float32).reshape(k, 1)]
     if masked:
         in_specs.append(n_spec)
         args.append(active.astype(jnp.int32))
+    args = [_pad_rows(a, rows) for a in args]
+    k_pad = args[0].shape[0]
     call = pl.pallas_call(
         kernel,
-        grid=(k,),
+        grid=(k_pad // rows,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n, m), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, n, m), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        out_specs=pl.BlockSpec((rows, n, m), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k_pad, n, m), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )
     if isinstance(loads, jax.core.Tracer):
         # under a jit trace the launch is timed by the caller's spans
-        return call(*args)
+        return call(*args)[:k]
     with _span("kernel.move_eval", chains=k, n=n, m=m,
                interpret=bool(interpret)):
-        return call(*args)
+        return call(*args)[:k]

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, s_out_ref,
@@ -64,7 +63,7 @@ def rwkv6_wkv_fwd(r, k, v, w, u, s0, *, interpret: bool = False):
         out_shape=[jax.ShapeDtypeStruct((b, t, h, hd), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, hd, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(r, k, v, w, u, s0)

@@ -217,13 +217,13 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
     alert evaluator (``repro.telemetry.alerts``) through the scan --
     O(1) observability state regardless of T.  ``valid`` (bool[T],
     optional, fleet-internal) gates sketch/alert updates on padded
-    bucket steps so a padded run's observability state is bit-identical
-    to the direct run's.
+    bucket steps so a padded run's observability state agrees with the
+    direct run's (``repro.lagsim.metrics.agrees``).
     """
     n = trace.shape[1]
     if cfg.fused_steps and fused_mode(policy, cfg, n) == "fused":
         # heuristic family under fused_steps: the multi-step fused path
-        # (repro.lagsim.fused) replaces the per-step scan, bit-exactly
+        # (repro.lagsim.fused) replaces the per-step scan
         return simulate_fused(trace, initial_lag, policy, cfg, active=active,
                               record_assign=record_assign, valid=valid)
     m = 2 * n + 2                       # packer bin-name universe

@@ -20,10 +20,12 @@ carry-dependent and what is not:
   (``2n+2`` names) packed into int32 bitmasks -- hence the
   ``FUSED_MAX_PARTITIONS`` gate (``2n+1 <= 30`` bits).
 
-The decomposition is bit-exact: ``fused == unfused`` for every
-trajectory field, every scenario family (``topic_lifecycle`` masking
-included), direct and fleet-padded (tests/test_fused_loop.py; the
-``python -m repro.lagsim.fused`` smoke asserts it in CI).
+The decomposition changes no decision: ``fused`` agrees with
+``unfused`` (``repro.lagsim.metrics.agrees``: integer fields exact, lag
+to rounding) for every trajectory field, every scenario family
+(``topic_lifecycle`` masking included), direct and fleet-padded
+(tests/test_fused_loop.py; the ``python -m repro.lagsim.fused`` smoke
+asserts it in CI).
 
 Routing (``LagSimConfig.fused_steps > 0``):
 
@@ -552,13 +554,12 @@ def simulate_fused(trace: jax.Array, initial_lag: jax.Array, policy: str,
 
 
 def _smoke() -> None:      # pragma: no cover - exercised by CI, not pytest
-    """CI fused smoke: jnp fused == unfused bit-for-bit on a masked
-    lifecycle workload, and the interpret-mode megakernel == the fused
-    engine (its pinned oracle) on the same run."""
-    import numpy as np
-
+    """CI fused smoke: the jnp fused engine and the interpret-mode
+    megakernel agree with the unfused scan on a masked lifecycle
+    workload."""
     from repro.core.scenarios import generate_masked_scenario
     from repro.lagsim.engine import LagSimConfig, sweep_lag
+    from repro.lagsim.metrics import agrees
 
     pols = ("NF", "FFD", "BFD", "WF")
     speeds, act = generate_masked_scenario(
@@ -574,10 +575,9 @@ def _smoke() -> None:      # pragma: no cover - exercised by CI, not pytest
         got = sweep_lag(pols, speeds, cfg, active=act)
         for f in ("lag_total", "lag_max", "consumers", "migrations",
                   "unreadable"):
-            a, b_ = np.asarray(getattr(got, f)), np.asarray(getattr(ref, f))
-            assert np.array_equal(a, b_), (
+            assert agrees(getattr(got, f), getattr(ref, f)), (
                 f"{label}: field {f} diverged from the unfused oracle")
-        print(f"fused smoke OK: {label} == unfused bit-for-bit "
+        print(f"fused smoke OK: {label} agrees with unfused "
               f"({len(pols)} policies, masked lifecycle, T % K != 0)")
 
 

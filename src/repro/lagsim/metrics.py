@@ -18,12 +18,21 @@ per (policy, scenario):
 All functions are plain numpy over trailing-time arrays ``[..., T]`` so
 they work on a single ``LagTrace`` and on stacked ``[P, B, T]`` sweeps
 alike.
+
+``agrees`` states when two runs of the same simulation that differ only
+in program shape or backend (fleet padding, the fused engine, the Pallas
+kernels, CPU vs TPU) count as the same result.
 """
 from __future__ import annotations
 
 from typing import Dict
 
 import numpy as np
+
+#: float fields of two agreeing runs differ by at most this fraction of
+#: the field's largest finite magnitude along its last axis (its scale:
+#: a trajectory's peak over time, a sketch's over channels)
+FLOAT_RTOL = 1e-4
 
 SLO_METRIC_NAMES = ("peak_lag", "mean_lag", "violation_frac", "time_to_drain",
                     "consumer_seconds", "total_migrations")
@@ -65,3 +74,26 @@ def summarize_sweep(result, cfg) -> Dict[str, np.ndarray]:
     """
     return slo_summary(result.lag_total, result.consumers, result.migrations,
                        slo_lag=cfg.slo_lag_or_default, dt=cfg.dt)
+
+
+def agrees(got, want) -> bool:
+    """The agreement contract on one field of two runs.
+
+    Integer and bool fields -- the decisions (consumers, migrations,
+    assignments) and every count -- must be equal.  Float fields may
+    differ by rounding: programs of different shapes, and different
+    backends, reduce in different orders, so only ``|got - want| <=
+    FLOAT_RTOL * scale`` is required, where ``scale`` is ``want``'s
+    largest finite magnitude along the last axis; equal infinities and
+    NaN positions must match exactly.
+    """
+    a, b = np.asarray(got), np.asarray(want)
+    if a.shape != b.shape or a.dtype != b.dtype:
+        return False
+    if not np.issubdtype(b.dtype, np.floating):
+        return bool(np.array_equal(a, b))
+    mag = np.where(np.isfinite(b), np.abs(b), 0.0)
+    scale = np.max(mag, axis=-1, keepdims=True) if b.ndim else mag
+    with np.errstate(invalid="ignore"):
+        close = np.abs(a - b) <= FLOAT_RTOL * scale
+    return bool(np.all((a == b) | (np.isnan(a) & np.isnan(b)) | close))

@@ -20,8 +20,9 @@ emits the pre-existing program bit-for-bit.
 
 The update takes an optional ``valid`` scalar so the fleet layer's
 bucket padding stays exact: a padded timestep leaves the sketch state
-untouched (``where(valid, new, old)``), so a padded run's sketch equals
-the direct run's bit-for-bit.  Host-side, :class:`SketchSummary`
+untouched (``where(valid, new, old)``), so a padded run's sketch agrees
+with the direct run's (``repro.lagsim.metrics.agrees``: counts exact,
+floats to rounding).  Host-side, :class:`SketchSummary`
 finalizes a state (debiasing EWMAs, deriving stddev and quantiles) and
 **merges across buckets/scenarios** with Chan's parallel-variance
 update, so a fleet of thousands of scenarios reduces to one summary
@@ -145,8 +146,8 @@ def sketch_update(cfg: SketchConfig, state: SketchState, vec: jax.Array,
     """One O(K) update with the step's channel vector ``f32[K]``.
 
     ``valid`` (scalar bool, optional) gates the update: a ``False`` step
-    (fleet bucket padding) leaves every aggregate untouched, keeping
-    padded runs bit-identical to direct runs.
+    (fleet bucket padding) leaves every aggregate untouched, so padded
+    runs agree with direct runs.
     """
     c1 = state.count + 1.0
     d = vec - state.mean
