@@ -373,30 +373,42 @@ def pack(speeds, capacity: float, *, algorithm: str = "BFD",
         speeds_of = dict(speeds)
         prev = dict(prev) if prev else None
         res = fn(speeds_of, capacity, prev=prev)
-        assignment = dict(res.pid_to_bin)
-        loads = {int(c): float(l) for c, l in res.loads.items()}
-        n_bins = res.n_bins
     else:
         import jax.numpy as jnp
 
         sp = np.asarray(speeds, np.float64)
         pv = (np.full(sp.shape[0], -1, np.int32) if prev is None
               else np.asarray(prev, np.int32))
-        res = fn(jnp.asarray(sp, jnp.float32), jnp.asarray(pv), capacity)
-        bin_of = np.asarray(res.bin_of)
-        n_bins = int(res.n_bins)
-        assignment = {int(j): int(c) for j, c in enumerate(bin_of)}
-        names = np.asarray(res.names)[:n_bins]
-        lds = np.asarray(res.loads)[:n_bins]
-        loads = {int(c): float(l) for c, l in zip(names, lds)}
-        speeds_of = {int(j): float(w) for j, w in enumerate(sp)}
-        prev = ({int(j): int(c) for j, c in enumerate(pv) if c >= 0}
-                if prev is not None else None)
-    r = None
-    if prev:
-        from repro.core.rscore import rscore
+        with span("pack.put"):
+            args = (jnp.asarray(sp, jnp.float32), jnp.asarray(pv))
+        with span("pack.run"):  # returns before the device finishes
+            res = fn(*args, capacity)
+        with span("pack.read") as counts:
+            bin_of, n_bins, names, lds = (
+                np.asarray(a)
+                for a in (res.bin_of, res.n_bins, res.names, res.loads))
+            if counts is not None:
+                counts.update(arrays=4, bytes=int(
+                    bin_of.nbytes + n_bins.nbytes + names.nbytes
+                    + lds.nbytes))
+    with span("pack.reply"):
+        if backend == "py":
+            assignment = dict(res.pid_to_bin)
+            loads = {int(c): float(l) for c, l in res.loads.items()}
+            n_bins = res.n_bins
+        else:
+            n_bins = int(n_bins)
+            assignment = {int(j): int(c) for j, c in enumerate(bin_of)}
+            loads = {int(c): float(l)
+                     for c, l in zip(names[:n_bins], lds[:n_bins])}
+            speeds_of = {int(j): float(w) for j, w in enumerate(sp)}
+            prev = ({int(j): int(c) for j, c in enumerate(pv) if c >= 0}
+                    if prev is not None else None)
+        r = None
+        if prev:
+            from repro.core.rscore import rscore
 
-        r = rscore(prev, assignment, speeds_of, capacity)
+            r = rscore(prev, assignment, speeds_of, capacity)
     return PackOutcome(algorithm=name, backend=backend,
                        capacity=float(capacity), n_bins=int(n_bins),
                        assignment=assignment, loads=loads, rscore=r)
@@ -470,17 +482,20 @@ def simulate(traces, *, policies: Optional[Sequence[str]] = None,
                 else np.asarray(traces).shape[-1])  # fail fast on bad knobs
     runner = fleet if fleet is not None else default_fleet()
     res = runner.simulate(tuple(policies), traces, cfg, active=active)
-    st = res.stacked()
-    metrics = {k: np.asarray(v)
-               for k, v in res.summarize(cfg, stacked=st).items()}
+    with span("sim.summarize"):
+        st = res.stacked()
+        metrics = {k: np.asarray(v)
+                   for k, v in res.summarize(cfg, stacked=st).items()}
     sketches = None
     if res.sketch is not None:
-        sketches = [[s for _, s in res.sketch_summaries(i)]
-                    for i in range(len(res.sketch))]
+        with span("sim.sketches"):
+            sketches = [[s for _, s in res.sketch_summaries(i)]
+                        for i in range(len(res.sketch))]
     incidents = None
     if res.incidents is not None:
-        incidents = [res.scenario_incidents(i)
-                     for i in range(len(res.incidents))]
+        with span("sim.incidents"):
+            incidents = [res.scenario_incidents(i)
+                         for i in range(len(res.incidents))]
     return SimulateOutcome(policies=res.policies, metrics=metrics,
                            lag_total=st["lag_total"],
                            consumers=st["consumers"],

@@ -294,7 +294,6 @@ class FleetRunner:
         if entry is not None:
             self._hits += 1
             self._bucket_stats(bucket)["hits"] += 1
-            _instant("fleet.cache_hit", bucket=bucket)
             self._cache.move_to_end(key)
             return entry[0]
         self._misses += 1
@@ -521,17 +520,24 @@ class FleetRunner:
                 lambda tr, ac, va: _sweep_impl(policies, tr, rcfg, ac, va))
         fn = self._compiled(key, build, args, bucket)
         res = self._dispatch(key, fn, args, bucket)
-        arrays = {f: np.asarray(getattr(res, f)) for f in self._SIM_FIELDS}
-        tele = res.telemetry
-        if tele is not None:
-            tele = TelemetryFrame(
-                channels=np.asarray(tele.channels),   # [P, B, T, K]
-                steps=np.asarray(tele.steps),         # [P, B, T]
-                count=np.asarray(tele.count),         # [P, B]
-                names=tele.names)
-        to_np = lambda obj: (None if obj is None else
-                             jax.tree_util.tree_map(np.asarray, obj))
-        return arrays, tele, to_np(res.sketch), to_np(res.incidents)
+        with _span("fleet.read") as counts:
+            arrays = {f: np.asarray(getattr(res, f))
+                      for f in self._SIM_FIELDS}
+            tele = res.telemetry
+            if tele is not None:
+                tele = TelemetryFrame(
+                    channels=np.asarray(tele.channels),   # [P, B, T, K]
+                    steps=np.asarray(tele.steps),         # [P, B, T]
+                    count=np.asarray(tele.count),         # [P, B]
+                    names=tele.names)
+            to_np = lambda obj: (None if obj is None else
+                                 jax.tree_util.tree_map(np.asarray, obj))
+            sk, inc = to_np(res.sketch), to_np(res.incidents)
+            if counts is not None:
+                read = jax.tree_util.tree_leaves((arrays, tele, sk, inc))
+                counts.update(arrays=len(read),
+                              bytes=sum(a.nbytes for a in read))
+        return arrays, tele, sk, inc
 
     @staticmethod
     def _scenario_frame(tele: TelemetryFrame, slot: int,
@@ -613,17 +619,18 @@ class FleetRunner:
             arrays, tele, sk, inc = self._run_sim(policies, speeds, act,
                                                   rcfg, t, n)
             sk_cfg = None if rcfg.telemetry is None else rcfg.telemetry.sketch
-            result = FleetLagResult(policies=policies, **{
-                f: [arrays[f][:, i] for i in range(b)]
-                for f in self._SIM_FIELDS},
-                telemetry=None if tele is None else [
-                    self._scenario_frame(tele, i, t) for i in range(b)],
-                sketch=None if sk is None else [
-                    self._scenario_state(sk, i) for i in range(b)],
-                sketch_configs=None if sk is None else [sk_cfg] * b,
-                incidents=None if inc is None else [
-                    self._scenario_state(inc, i) for i in range(b)],
-                alert_config=alert_cfg, dt=cfg.dt)
+            with _span("fleet.unpack", scenarios=b):
+                result = FleetLagResult(policies=policies, **{
+                    f: [arrays[f][:, i] for i in range(b)]
+                    for f in self._SIM_FIELDS},
+                    telemetry=None if tele is None else [
+                        self._scenario_frame(tele, i, t) for i in range(b)],
+                    sketch=None if sk is None else [
+                        self._scenario_state(sk, i) for i in range(b)],
+                    sketch_configs=None if sk is None else [sk_cfg] * b,
+                    incidents=None if inc is None else [
+                        self._scenario_state(inc, i) for i in range(b)],
+                    alert_config=alert_cfg, dt=cfg.dt)
             if progress is not None:
                 progress(self._progress_snapshot(result, b, b, f"{t}x{n}"))
             return result
@@ -655,20 +662,21 @@ class FleetRunner:
             arrays, tele, sk, inc = self._run_sim(policies, speeds, act,
                                                   rcfg, tb, nb, valid)
             sk_cfg = None if rcfg.telemetry is None else rcfg.telemetry.sketch
-            for slot, (idx, sp, _) in enumerate(members):
-                t = sp.shape[0]
-                for f in self._SIM_FIELDS:
-                    outs[f][idx] = arrays[f][:, slot, :t]
-                if tele is not None:
-                    any_tele = True
-                    tele_out[idx] = self._scenario_frame(tele, slot, t)
-                if sk is not None:
-                    any_sk = True
-                    sk_out[idx] = self._scenario_state(sk, slot)
-                    sk_cfg_out[idx] = sk_cfg
-                if inc is not None:
-                    any_inc = True
-                    inc_out[idx] = self._scenario_state(inc, slot)
+            with _span("fleet.unpack", scenarios=len(members)):
+                for slot, (idx, sp, _) in enumerate(members):
+                    t = sp.shape[0]
+                    for f in self._SIM_FIELDS:
+                        outs[f][idx] = arrays[f][:, slot, :t]
+                    if tele is not None:
+                        any_tele = True
+                        tele_out[idx] = self._scenario_frame(tele, slot, t)
+                    if sk is not None:
+                        any_sk = True
+                        sk_out[idx] = self._scenario_state(sk, slot)
+                        sk_cfg_out[idx] = sk_cfg
+                    if inc is not None:
+                        any_inc = True
+                        inc_out[idx] = self._scenario_state(inc, slot)
             done += len(members)
             if progress is not None:
                 result.sketch = sk_out if any_sk else None

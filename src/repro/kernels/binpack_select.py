@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.telemetry.spans import span as _span
-
 from ._compat import default_interpret as _default_interpret
 from ._compat import pad_rows as _pad_rows
 from ._compat import row_tile as _row_tile
@@ -125,12 +123,7 @@ def select_slot_grid(loads, w, k, capacity, *, active=None,
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )
-    if isinstance(loads, jax.core.Tracer):
-        # under a jit trace the launch is timed by the caller's spans
-        return call(*args)[:b, :n]
-    with _span("kernel.select_slot", batch=b, n=n, m=m, strategy=strategy,
-               interpret=bool(interpret)):
-        return call(*args)[:b, :n]
+    return call(*args)[:b, :n]
 
 
 def select_slot_batch(loads, w, k, capacity, *, active=None,

@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.telemetry.spans import span as _span
-
 from ._compat import default_interpret as _default_interpret
 from ._compat import pad_rows as _pad_rows
 from ._compat import row_tile as _row_tile
@@ -150,13 +148,7 @@ def lag_update_batch(lag, produced, assign, readable, cap, *, active=None,
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )
-    if isinstance(lag, jax.core.Tracer):
-        # inside a jit trace: launch cost belongs to the enclosing
-        # fleet.compile / fleet.dispatch spans, not a per-step host span
-        return call(*args)[:b]
-    with _span("kernel.lag_update", batch=b, n=n, m=m,
-               interpret=bool(interpret)):
-        return call(*args)[:b]
+    return call(*args)[:b]
 
 
 def lag_update_single(lag, produced, assign, readable, cap, *, active=None,

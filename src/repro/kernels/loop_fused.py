@@ -45,8 +45,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.telemetry.spans import span as _span
-
 from ._compat import default_interpret as _default_interpret
 from ._compat import pad_rows as _pad_rows
 from ._compat import row_tile as _row_tile
@@ -267,7 +265,6 @@ def loop_fused_batch(rates, *, strategy: str, decreasing: bool,
     if interpret is None:
         interpret = _default_interpret()
     masked = active is not None
-    traced = isinstance(rates, jax.core.Tracer)
     t_blocks = -(-t // k_blk)
     t_pad = t_blocks * k_blk
     rows = _row_tile(b)
@@ -315,13 +312,6 @@ def loop_fused_batch(rates, *, strategy: str, decreasing: bool,
         interpret=interpret,
     )
 
-    def run(*a):
-        *steps, asg = call(*a)
-        steps = [o[:t, :b, 0].T for o in steps]
-        return tuple(steps) + (jnp.swapaxes(asg[:t, :b], 0, 1),)
-
-    if traced:
-        return run(*args)
-    with _span("kernel.loop_fused", batch=b, t=t, n=n, k=k_blk,
-               interpret=bool(interpret)):
-        return run(*args)
+    *steps, asg = call(*args)
+    steps = [o[:t, :b, 0].T for o in steps]
+    return tuple(steps) + (jnp.swapaxes(asg[:t, :b], 0, 1),)

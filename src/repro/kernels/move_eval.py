@@ -43,8 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.telemetry.spans import span as _span
-
 from ._compat import default_interpret as _default_interpret
 from ._compat import pad_rows as _pad_rows
 from ._compat import row_tile as _row_tile
@@ -190,9 +188,4 @@ def move_delta_batch(loads, counts, assign, speeds, prev, lam, cap, *,
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )
-    if isinstance(loads, jax.core.Tracer):
-        # under a jit trace the launch is timed by the caller's spans
-        return call(*args)[:k]
-    with _span("kernel.move_eval", chains=k, n=n, m=m,
-               interpret=bool(interpret)):
-        return call(*args)[:k]
+    return call(*args)[:k]

@@ -412,13 +412,19 @@ def otlp_spans_json(records: Sequence[Any],
                     epoch_unix_nano: int = 0) -> Dict[str, Any]:
     """OTLP/JSON ``resourceSpans`` from ``Tracer.records()`` --
     span times are tracer-epoch-relative microseconds, offset by
-    ``epoch_unix_nano`` (default 0: deterministic output)."""
+    ``epoch_unix_nano`` (default 0: deterministic output).  Each span
+    keeps its record's ``spanId`` and ``parentSpanId``; the spans of one
+    root (one ``api.*`` call) share the ``traceId`` made from its
+    ``root_id``."""
     spans = []
-    for i, r in enumerate(records):
+    for r in records:
         start = int(epoch_unix_nano) + int(r.start_us * 1_000)
+        link = ({} if r.parent_id is None
+                else {"parentSpanId": f"{r.parent_id:016x}"})
         spans.append({
-            "traceId": "0" * 31 + "1",
-            "spanId": f"{i + 1:016x}",
+            "traceId": f"{r.root_id:032x}",
+            "spanId": f"{r.span_id:016x}",
+            **link,
             "name": r.name,
             "kind": 1,                                 # SPAN_KIND_INTERNAL
             "startTimeUnixNano": str(start),

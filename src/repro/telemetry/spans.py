@@ -15,6 +15,17 @@ dispatch split the ROADMAP's megakernel item needs a baseline for.
 ``trace_event`` JSON -- load it at https://ui.perfetto.dev (or
 ``chrome://tracing``) to see a ``fleet_bench`` run as a timeline.
 
+Every record also carries its causality: ``span_id``, the ``parent_id``
+of the span open around it on the same thread (``None`` at the top), and
+the ``root_id`` of the outermost one, so all spans of one ``api.*`` call
+share a ``root_id``.  Records are appended as spans *end*, so a child
+precedes its parent in ``records()``.
+
+Once ``jax`` is imported, each span also enters
+``jax.profiler.TraceAnnotation(name)`` for its duration, so under a
+running profiler it appears on the ``/host:CPU`` plane of the trace,
+nested as recorded, on the same clock as the device's operations.
+
 Everything here is stdlib-only (no jax import), so ``repro.api`` can
 instrument its verbs without losing its jax-free import.  The tracer is
 a bounded flight recorder: past ``max_spans`` records new spans are
@@ -25,7 +36,9 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import itertools
 import json
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional
@@ -41,6 +54,18 @@ class SpanRecord:
     call_index: int            # nth occurrence of this name (0 = first call)
     tid: int                   # host thread id
     args: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    span_id: int = 0           # unique within the tracer, from 1
+    parent_id: Optional[int] = None   # enclosing span on this thread
+    root_id: int = 0           # outermost enclosing span (own id at top)
+
+
+def _annotation(name: str):
+    """The profiler's host annotation for ``name`` once jax is loaded (a
+    no-op context otherwise: this module never imports jax)."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    if prof is None:
+        return contextlib.nullcontext()
+    return prof.TraceAnnotation(name)
 
 
 class Tracer:
@@ -56,6 +81,8 @@ class Tracer:
         self.spans: List[SpanRecord] = []
         self.dropped = 0
         self._counts: Dict[str, int] = {}
+        self._ids = itertools.count(1)
+        self._local = threading.local()     # .stack: open span ids
 
     # -- recording ----------------------------------------------------------
 
@@ -72,17 +99,26 @@ class Tracer:
         """Time the enclosed block as one span.
 
         Yields the (mutable) args dict so the block can attach results
-        discovered mid-span (e.g. a cache-hit flag); yields ``None`` when
-        the tracer is disabled.
+        discovered mid-span (e.g. a byte count); yields ``None`` when the
+        tracer is disabled.
         """
         if not self.enabled:
             yield None
             return
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        sid = next(self._ids)
+        parent = stack[-1] if stack else None
+        root = stack[0] if stack else sid
+        stack.append(sid)
         t0 = time.perf_counter()
         try:
-            yield args
+            with _annotation(name):
+                yield args
         finally:
             t1 = time.perf_counter()
+            stack.pop()
             with self._lock:
                 idx = self._counts.get(name, 0)
                 self._counts[name] = idx + 1
@@ -95,10 +131,11 @@ class Tracer:
                         dur_us=(t1 - t0) * 1e6,
                         call_index=idx,
                         tid=threading.get_ident(),
-                        args=dict(args)))
+                        args=dict(args),
+                        span_id=sid, parent_id=parent, root_id=root))
 
     def instant(self, name: str, **args: Any) -> None:
-        """Record a zero-duration event (cache hits/misses, evictions)."""
+        """Record a zero-duration event (cache misses, evictions)."""
         with self.span(name, **args):
             pass
 
@@ -158,7 +195,7 @@ class Tracer:
         """Chrome ``trace_event`` JSON (the format Perfetto ingests).
 
         Complete ``ph: "X"`` duration events on one process track, one
-        thread row per host thread; span args ride along for the
+        thread row per host thread; span args and ids ride along for the
         Perfetto details pane.
         """
         events: List[Dict[str, Any]] = [{
@@ -174,7 +211,9 @@ class Tracer:
                 "dur": r.dur_us,
                 "pid": 0,
                 "tid": r.tid,
-                "args": {**r.args, "call_index": r.call_index},
+                "args": {**r.args, "call_index": r.call_index,
+                         "span_id": r.span_id, "parent_id": r.parent_id,
+                         "root_id": r.root_id},
             })
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
@@ -228,7 +267,7 @@ def traced(name: Optional[str] = None) -> Callable:
 
 
 def instant(name: str, **args: Any) -> None:
-    """Zero-duration event on the default tracer (cache hits, evictions)."""
+    """Zero-duration event on the default tracer (cache misses, evictions)."""
     _DEFAULT.instant(name, **args)
 
 

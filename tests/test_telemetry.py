@@ -338,6 +338,10 @@ def test_tracer_chrome_trace_valid(tmp_path):
     assert by_name["outer"]["args"]["label"] == "x"
     assert by_name["marker"]["args"]["hit"] is True
     assert by_name["marker"]["dur"] >= 0.0
+    assert by_name["marker"]["args"]["parent_id"] == \
+        by_name["outer"]["args"]["span_id"]
+    assert by_name["outer"]["args"]["root_id"] == \
+        by_name["outer"]["args"]["span_id"]
 
 
 def test_tracer_bounded():
@@ -390,6 +394,117 @@ def test_validate_chrome_trace_rejects_garbage():
         validate_chrome_trace({})
 
 
+def test_span_ids_nested_and_per_thread():
+    import threading
+
+    tr = Tracer()
+    with tr.span("root"):
+        with tr.span("child"):
+            tr.instant("leaf")
+        with tr.span("sibling"):
+            pass
+    with tr.span("next"):
+        pass
+    rec = {r.name: r for r in tr.records()}
+    # appended as each span ends: children before their parent
+    assert [r.name for r in tr.records()] == [
+        "leaf", "child", "sibling", "root", "next"]
+    root = rec["root"]
+    assert root.parent_id is None and root.root_id == root.span_id
+    assert rec["child"].parent_id == root.span_id
+    assert rec["sibling"].parent_id == root.span_id
+    assert rec["leaf"].parent_id == rec["child"].span_id
+    assert {rec[n].root_id for n in ("child", "sibling", "leaf")} == {
+        root.span_id}
+    assert rec["next"].parent_id is None
+    assert rec["next"].root_id == rec["next"].span_id != root.span_id
+    assert len({r.span_id for r in tr.records()}) == 5
+
+    # each thread keeps its own stack: a span opened on another thread
+    # while "outer" is open here is a root of its own
+    tr.reset()
+    opened, release = threading.Event(), threading.Event()
+
+    def worker():
+        with tr.span("t.outer"):
+            opened.set()
+            release.wait(5)
+            with tr.span("t.inner"):
+                pass
+
+    th = threading.Thread(target=worker)
+    with tr.span("main.outer"):
+        th.start()
+        opened.wait(5)
+        with tr.span("main.inner"):
+            pass
+        release.set()
+        th.join(5)
+    rec = {r.name: r for r in tr.records()}
+    for side in ("main", "t"):
+        outer, inner = rec[f"{side}.outer"], rec[f"{side}.inner"]
+        assert outer.parent_id is None
+        assert inner.parent_id == outer.span_id == inner.root_id
+    assert rec["main.outer"].tid != rec["t.outer"].tid
+
+
+def test_spans_on_the_profiler_timeline(tmp_path):
+    """Under ``jax.profiler.trace`` every program span is a host event of
+    the profile, nested as recorded."""
+    import glob
+
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("prof.root"):
+            with tr.span("prof.child"):
+                jnp.ones(8).block_until_ready()
+            tr.instant("prof.mark")
+    paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert len(paths) == 1
+    prof = jax.profiler.ProfileData.from_file(paths[0])
+    found = {}
+    for plane in prof.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("prof."):
+                    s = int(ev.start_ns)
+                    found[ev.name] = (line.name, s, s + int(ev.duration_ns))
+    assert set(found) == {"prof.root", "prof.child", "prof.mark"}
+    (line, r0, r1), (cline, c0, c1), (mline, m0, _) = (
+        found["prof.root"], found["prof.child"], found["prof.mark"])
+    assert line == cline == mline
+    assert r0 <= c0 <= c1 <= r1 and c1 <= m0 <= r1
+    rec = {r.name: r for r in tr.records()}
+    assert rec["prof.child"].parent_id == rec["prof.root"].span_id
+
+
+def test_otlp_spans_carry_parent_links_and_trace_ids():
+    from repro.telemetry import otlp_spans_json
+
+    tr = Tracer()
+    for _ in range(2):
+        with tr.span("api.call"):
+            with tr.span("step.a"):
+                pass
+            with tr.span("step.b"):
+                pass
+    out = otlp_spans_json(tr.records())
+    spans = out["resourceSpans"][0]["scopeSpans"][0]["spans"]
+    by_id = {s["spanId"]: s for s in spans}
+    assert len(by_id) == 6
+    roots = [s for s in spans if s["name"] == "api.call"]
+    assert len(roots) == 2 and all("parentSpanId" not in s for s in roots)
+    assert roots[0]["traceId"] != roots[1]["traceId"]
+    for s in spans:
+        assert len(s["traceId"]) == 32 and len(s["spanId"]) == 16
+        if s["name"].startswith("step."):
+            parent = by_id[s["parentSpanId"]]
+            assert parent["name"] == "api.call"
+            assert s["traceId"] == parent["traceId"]
+
+
 # ---------------------------------------------------------------------------
 # fleet runner: per-bucket stats, reset, AOT spans
 # ---------------------------------------------------------------------------
@@ -413,6 +528,80 @@ def test_fleet_stats_per_bucket_and_reset():
     st3 = fleet.stats()
     assert st3["cache_misses"] == 0 and st3["cache_hits"] >= 1
     assert st3["per_bucket"]["32x8"]["hits"] >= 1
+
+
+def test_api_pack_emits_child_spans():
+    from repro import api
+    from repro.telemetry import default_tracer
+
+    tracer = default_tracer()
+    n0 = len(tracer.records())
+    w = np.linspace(0.05, 0.6, 12)
+    first = api.pack(w, 1.0, algorithm="MBFP", backend="jax")
+    prev = np.array([first.assignment[j] for j in range(12)], np.int32)
+    out = api.pack(w, 1.0, algorithm="MBFP", prev=prev, backend="jax")
+    assert out.rscore is not None
+    recs = tracer.records()[n0:]
+    roots = [r for r in recs if r.name == "api.pack"]
+    assert len(roots) == 2 and all(r.parent_id is None for r in roots)
+    for root in roots:
+        kids = [r for r in recs if r.parent_id == root.span_id]
+        assert [r.name for r in kids] == [
+            "pack.put", "pack.run", "pack.read", "pack.reply"]
+        assert all(r.root_id == root.span_id for r in kids)
+        read = kids[2]
+        assert read.args["arrays"] == 4
+        # bin_of i32[12], n_bins i32, names i32[m], loads f32[m]
+        assert read.args["bytes"] >= 4 * (12 + 1 + 1 + 1)
+        assert sum(r.dur_us for r in kids) <= root.dur_us
+
+
+def test_api_simulate_emits_read_and_post_spans():
+    from repro import api
+    from repro.telemetry import (AlertConfig, SketchConfig, default_rules,
+                                 default_tracer)
+
+    tracer = default_tracer()
+    n0 = len(tracer.records())
+    speeds, active = _scenario(t=10, n=4)
+    tele = TelemetryConfig(record_frames=False, sketch=SketchConfig(),
+                           alerts=AlertConfig(rules=default_rules()))
+    out = api.simulate(speeds, policies=("KEDA_LAG",), config=CFG,
+                       active=active, telemetry=tele,
+                       fleet=FleetRunner(FleetConfig()))
+    assert out.sketches is not None and out.incidents is not None
+    recs = tracer.records()[n0:]
+    root, = [r for r in recs if r.name == "api.simulate"]
+    assert root.parent_id is None
+    mine = {r.name: r for r in recs if r.root_id == root.span_id}
+    for name in ("fleet.simulate", "fleet.dispatch", "fleet.read",
+                 "fleet.unpack", "sim.summarize", "sim.sketches",
+                 "sim.incidents"):
+        assert name in mine, (name, sorted(mine))
+    for name in ("sim.summarize", "sim.sketches", "sim.incidents",
+                 "fleet.simulate"):
+        assert mine[name].parent_id == root.span_id
+    fsim = mine["fleet.simulate"].span_id
+    assert mine["fleet.unpack"].parent_id == fsim
+    assert mine["fleet.read"].parent_id == fsim
+    # lag/consumer trajectories, sketch and alert state leaves
+    assert mine["fleet.read"].args["arrays"] > 5
+    assert mine["fleet.read"].args["bytes"] > 0
+
+
+def test_fleet_cache_hit_is_counted_not_traced():
+    from repro.telemetry import default_tracer
+
+    tracer = default_tracer()
+    speeds, active = _scenario(t=10, n=4)
+    fleet = FleetRunner(FleetConfig())
+    fleet.simulate(("MBFP",), speeds, CFG, active=active)
+    n0 = len(tracer.records())
+    fleet.simulate(("MBFP",), speeds, CFG, active=active)
+    assert fleet.stats()["per_bucket"]["10x4"]["hits"] == 1
+    names = [r.name for r in tracer.records()[n0:]]
+    assert "fleet.cache_miss" not in names
+    assert not any(n.startswith("fleet.cache_hit") for n in names)
 
 
 def test_fleet_emits_aot_spans():
