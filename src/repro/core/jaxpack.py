@@ -3,9 +3,16 @@
 Pure ``jax.lax`` control flow (scan over items, masked argmin/argmax over
 bins), so a whole 500-iteration stream evaluation jit-compiles into a single
 XLA program and the packer can run *inside* the controller's jitted decision
-step on device.  Semantics (including tie-breaking and the Sec. IV-C sticky
-naming rule) match ``binpack.py`` / ``modified.py`` bit-for-bit; the property
-tests in ``tests/test_jaxpack.py`` enforce exact agreement.
+step on device.  Inside a scan body the carried state is read and written
+only by one-hot selects against an iota (``jnp.where(iota == slot, ...)``
+and masked reductions), never at a traced index: under the fleet's
+``jax.vmap`` a traced-index access is a batched gather or scatter, a slow
+serialized op on TPU, where a select is elementwise work that fuses.  The
+per-item inputs are permuted into scan order once, before the loop, and
+fed as the scan's ``xs``.  Semantics (including tie-breaking and the
+Sec. IV-C sticky naming rule) match ``binpack.py`` / ``modified.py``
+bit-for-bit; the property tests in ``tests/test_jaxpack.py`` enforce exact
+agreement.
 
 Conventions
 -----------
@@ -43,15 +50,28 @@ class PackedJax:
     n_bins: jax.Array   # i32[]   number of created bins
 
 
+def _pick(x, hot):
+    """``x[i]`` where ``hot = iota == i``: a one-hot masked reduction.
+
+    A traced-index read ``x[i]`` is a gather under ``vmap`` (a batched one on
+    the fleet path); the reduction is elementwise work on every backend.
+    """
+    if x.dtype == jnp.bool_:
+        return jnp.any(hot & x, axis=-1)
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return jnp.max(jnp.where(hot, x, -jnp.inf), axis=-1)
+    return jnp.sum(jnp.where(hot, x, 0), axis=-1)
+
+
 def _select_slot(loads, k, w, capacity, strategy: str):
     """Masked fit-strategy selection over created slots [0, k). Returns
     (slot, found)."""
-    m = loads.shape[0]
-    created = jnp.arange(m) < k
+    iota = jnp.arange(loads.shape[0])
+    created = iota < k
     fits = created & (loads + w <= capacity)
     if strategy == "next":
         last = jnp.maximum(k - 1, 0)
-        ok = (k > 0) & fits[last]
+        ok = (k > 0) & _pick(fits, iota == last)
         return last, ok
     if strategy == "first":
         return jnp.argmax(fits), fits.any()
@@ -68,22 +88,37 @@ def _fresh_name(used, prev_name):
     """Sec. IV-C naming: the item's previous bin if still unused, else the
     lowest unused name."""
     lowest = jnp.argmin(used)                     # first False
-    sticky_ok = (prev_name >= 0) & ~used[jnp.clip(prev_name, 0)]
+    held = _pick(used, jnp.arange(used.shape[0]) == prev_name)
+    sticky_ok = (prev_name >= 0) & ~held
     return jnp.where(sticky_ok, prev_name, lowest)
 
 
-def _place_or_create(state, j, w, prev_name, capacity, strategy: str, sticky: bool):
-    """Any-fit insert of item ``j``: selected open bin, else a new bin."""
+def _place_or_create(state, j, w, prev_name, live, capacity, strategy: str,
+                     sticky: bool):
+    """Any-fit insert of item ``j``: selected open bin, else a new bin.
+
+    ``live`` (bool, or None for always) gates every write, so a dead item
+    leaves the state as it was.  Writes are one-hot selects against an
+    iota: under ``vmap`` an ``x.at[i].set`` with a traced ``i`` is a batched
+    scatter, a slow serialized op on TPU, where a select stays elementwise.
+    """
     loads, names, used, k, bin_of = state
     slot, found = _select_slot(loads, k, w, capacity, strategy)
     name_new = _fresh_name(used, prev_name if sticky else jnp.int32(NEG))
     slot = jnp.where(found, slot, k)
-    name = jnp.where(found, names[slot], name_new)
-    loads = loads.at[slot].add(w)
-    names = names.at[slot].set(name)
-    used = used.at[name].set(True)
-    k = jnp.where(found, k, k + 1)
-    bin_of = bin_of.at[j].set(name)
+    at_slot = jnp.arange(loads.shape[0]) == slot
+    name = jnp.where(found, _pick(names, at_slot), name_new)
+    at_name = jnp.arange(used.shape[0]) == name
+    at_j = jnp.arange(bin_of.shape[0]) == j
+    grow = ~found
+    if live is not None:
+        at_slot, at_name, at_j = at_slot & live, at_name & live, at_j & live
+        grow = grow & live
+    loads = jnp.where(at_slot, loads + w, loads)
+    names = jnp.where(at_slot, name, names)
+    used = used | at_name
+    k = jnp.where(grow, k + 1, k)
+    bin_of = jnp.where(at_j, name, bin_of)
     return loads, names, used, k, bin_of
 
 
@@ -111,20 +146,19 @@ def pack_jax(
     if active is not None:
         active = active.astype(bool)
 
+    # the item order is fixed before the loop, so every per-item input is
+    # permuted into it once and fed as the scan's xs
+    order = jnp.arange(n, dtype=jnp.int32)
+    xs = (speeds, prev, active)
     if decreasing:
         # stable non-increasing sort: (-speed, original index)
-        order = jnp.lexsort((jnp.arange(n), -speeds))
-    else:
-        order = jnp.arange(n)
+        order = jnp.lexsort((order, -speeds)).astype(jnp.int32)
+        xs = jax.tree_util.tree_map(lambda a: a[order], xs)
 
-    def body(state, j):
-        w = speeds[j]
-        new = _place_or_create(state, j, w, prev[j], capacity, strategy, sticky)
-        if active is not None:
-            # an inactive item leaves every piece of packing state untouched
-            new = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(active[j], a, b), new, state)
-        return new, None
+    def body(state, x):
+        j, (w, prev_name, live) = x
+        return _place_or_create(state, j, w, prev_name, live, capacity,
+                                strategy, sticky), None
 
     init = (
         jnp.zeros(m, jnp.float32),
@@ -133,7 +167,7 @@ def pack_jax(
         jnp.int32(0),
         jnp.full(n, NEG, jnp.int32),
     )
-    (loads, names, used, k, bin_of), _ = lax.scan(body, init, order)
+    (loads, names, used, k, bin_of), _ = lax.scan(body, init, (order, xs))
     return PackedJax(bin_of=bin_of, loads=loads, names=names, n_bins=k)
 
 
@@ -211,60 +245,63 @@ def modified_any_fit_jax(
     seq_items = seq_items[entry_order]
     seq_phase = seq_phase[entry_order]
 
+    iota_m = jnp.arange(m)
+    iota_u = jnp.arange(u)
+    iota_n = jnp.arange(n)
+
     def body(state, ent):
-        (loads, names, used, k, bin_of, placed, to_u, u_order,
-         fail1, own_slot, own_fail) = state
-        j, phase, entry_idx = ent
-        w = speeds[j]
-        c = cseg[j]
-        skip = placed[j] | ~assigned[j]
+        j, phase, entry_idx, w, c, asg = ent
+        at_j = iota_n == j
+        at_c = iota_u == c                    # s == u: one iota serves both
 
-        def phase1(args):
+        def phase1(state):
             (loads, names, used, k, bin_of, placed, to_u, u_order,
-             fail1, own_slot, own_fail, entry_idx) = args
+             fail1, own_slot, own_fail) = state
             slot, found = _select_slot(loads, k, w, capacity, fit)
-            found = found & ~fail1[c]
-            loads = jnp.where(found, loads.at[slot].add(w), loads)
-            bin_of = jnp.where(found, bin_of.at[j].set(names[slot]), bin_of)
-            placed = placed.at[j].set(placed[j] | found)
-            fail1 = fail1.at[c].set(fail1[c] | ~found)
+            found = found & ~_pick(fail1, at_c)
+            loads = jnp.where(found & (iota_m == slot), loads + w, loads)
+            bin_of = jnp.where(found & at_j, _pick(names, iota_m == slot), bin_of)
+            placed = placed | (found & at_j)
+            fail1 = fail1 | (~found & at_c)
             return (loads, names, used, k, bin_of, placed, to_u, u_order,
-                    fail1, own_slot, own_fail, entry_idx)
+                    fail1, own_slot, own_fail)
 
-        def phase2(args):
+        def phase2(state):
             (loads, names, used, k, bin_of, placed, to_u, u_order,
-             fail1, own_slot, own_fail, entry_idx) = args
+             fail1, own_slot, own_fail) = state
             # create the consumer's own bin (named c) on its first
             # still-unplaced item (pset nonempty <=> some phase-1 failure)
-            need_create = own_slot[c] < 0
-            slot_new = k
-            names = jnp.where(need_create, names.at[slot_new].set(c), names)
-            used = jnp.where(need_create, used.at[c].set(True), used)
-            own_slot = jnp.where(need_create, own_slot.at[c].set(slot_new), own_slot)
+            own = _pick(own_slot, at_c)
+            need_create = own < 0
+            own = jnp.where(need_create, k, own)
+            at_own = iota_m == own
+            names = jnp.where(need_create & at_own, c, names)
+            used = used | (need_create & at_c)
+            own_slot = jnp.where(need_create & at_c, own, own_slot)
             k = jnp.where(need_create, k + 1, k)
-            own = own_slot[c]
             # oversized exception: an item with w > C may hold its own
             # empty bin (matches modified.py; see comment there)
-            fits = ((loads[own] + w <= capacity) |
-                    ((loads[own] == 0.0) & (w > capacity))) & ~own_fail[c]
-            loads = jnp.where(fits, loads.at[own].add(w), loads)
-            bin_of = jnp.where(fits, bin_of.at[j].set(c), bin_of)
-            placed = placed.at[j].set(placed[j] | fits)
-            own_fail = own_fail.at[c].set(own_fail[c] | ~fits)
-            deferred = ~fits
-            to_u = to_u.at[j].set(to_u[j] | deferred)
-            u_order = jnp.where(deferred, u_order.at[j].set(n + entry_idx), u_order)
+            load_own = _pick(loads, at_own)
+            fits = ((load_own + w <= capacity) |
+                    ((load_own == 0.0) & (w > capacity))) & ~_pick(own_fail, at_c)
+            loads = jnp.where(fits & at_own, loads + w, loads)
+            bin_of = jnp.where(fits & at_j, c, bin_of)
+            placed = placed | (fits & at_j)
+            own_fail = own_fail | (~fits & at_c)
+            to_u = to_u | (~fits & at_j)
+            u_order = jnp.where(~fits & at_j, n + entry_idx, u_order)
             return (loads, names, used, k, bin_of, placed, to_u, u_order,
-                    fail1, own_slot, own_fail, entry_idx)
+                    fail1, own_slot, own_fail)
 
-        args = (loads, names, used, k, bin_of, placed, to_u, u_order,
-                fail1, own_slot, own_fail, entry_idx)
-        args = lax.cond(skip, lambda a: a,
-                        lambda a: lax.cond(phase == 0, phase1, phase2, a), args)
-        (loads, names, used, k, bin_of, placed, to_u, u_order,
-         fail1, own_slot, own_fail, _) = args
-        return (loads, names, used, k, bin_of, placed, to_u, u_order,
-                fail1, own_slot, own_fail), None
+        # Under the fleet's vmap both conds run as selects of their
+        # branches; unbatched (api.pack) they skip the entries with no work:
+        # an item's phase-2 entry once phase 1 placed it, and both entries
+        # of an unassigned item.
+        placed = state[5]
+        skip = _pick(placed, at_j) | ~asg
+        return lax.cond(skip, lambda st: st,
+                        lambda st: lax.cond(phase == 0, phase1, phase2, st),
+                        state), None
 
     init = (
         jnp.zeros(m, jnp.float32),            # loads
@@ -279,25 +316,25 @@ def modified_any_fit_jax(
         jnp.full(s, NEG, jnp.int32),          # own_slot per consumer
         jnp.zeros(s, bool),                   # own_fail per consumer
     )
-    ents = jnp.stack([seq_items, seq_phase, jnp.arange(2 * n, dtype=jnp.int32)], axis=1)
+    ents = (seq_items, seq_phase, jnp.arange(2 * n, dtype=jnp.int32),
+            speeds[seq_items], cseg[seq_items], assigned[seq_items])
     state, _ = lax.scan(body, init, ents)
     (loads, names, used, k, bin_of, placed, to_u, u_order, *_rest) = state
 
     # final stage (lines 27-29): decreasing any-fit over U with sticky naming
     final_order = jnp.lexsort((u_order, -speeds))
 
-    def fbody(state, j):
-        loads, names, used, k, bin_of = state
-        pending = to_u[j]
-
-        def do(args):
-            return _place_or_create(args, j, speeds[j], prev[j], capacity, fit, True)
-
-        state = lax.cond(pending, do, lambda a: a, (loads, names, used, k, bin_of))
-        return state, None
+    def fbody(state, x):
+        j, w, prev_name, pending = x
+        return lax.cond(
+            pending,
+            lambda st: _place_or_create(st, j, w, prev_name, None, capacity,
+                                        fit, True),
+            lambda st: st, state), None
 
     (loads, names, used, k, bin_of), _ = lax.scan(
-        fbody, (loads, names, used, k, bin_of), final_order)
+        fbody, (loads, names, used, k, bin_of),
+        (final_order, speeds[final_order], prev[final_order], to_u[final_order]))
     return PackedJax(bin_of=bin_of, loads=loads, names=names, n_bins=k)
 
 

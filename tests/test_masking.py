@@ -125,6 +125,39 @@ def test_mask_contract_fixed_instances(name, seed):
     _check_masked_absent(speeds, seed, name)
 
 
+def _masked_fleet(b=8, n=16, seed=3):
+    """A batch of masked instances: an all-inactive row, speed ties,
+    oversized items (w > C), and previous names at slots >= n."""
+    rng = np.random.default_rng(seed)
+    speeds = rng.integers(0, 1100, (b, n)) / 1024.0
+    speeds[1] = rng.choice([0.25, 0.5], n)              # ties in speed
+    speeds[2, ::3] = rng.integers(1025, 3072, len(speeds[2, ::3])) / 1024.0
+    prev = rng.integers(-1, max(1, n // 2), (b, n))
+    prev[3] = rng.integers(n, 2 * n + 1, n)             # names >= n
+    prev[4, ::2] = NEG
+    active = rng.random((b, n)) < 0.75
+    active[0] = False                                   # all inactive
+    active[5] = True
+    return (jnp.asarray(speeds, jnp.float32), jnp.asarray(prev, jnp.int32),
+            jnp.asarray(active))
+
+
+@pytest.mark.parametrize("name", sorted(ALGORITHMS))
+def test_vmapped_pack_equals_row_by_row(name):
+    """The fleet path (``vmap`` over masked rows) packs each row exactly
+    as the packer run on that row alone, bit for bit."""
+    fn = packer_for(name, backend="jax")
+    speeds, prev, active = _masked_fleet()
+    batched = jax.jit(jax.vmap(lambda s, p, a: fn(s, p, C, active=a)))(
+        speeds, prev, active)
+    for i in range(speeds.shape[0]):
+        row = fn(speeds[i], prev[i], C, active=active[i])
+        for field in ("bin_of", "loads", "names", "n_bins"):
+            got = np.asarray(getattr(batched, field))[i]
+            want = np.asarray(getattr(row, field))
+            assert got.tobytes() == want.tobytes(), (name, i, field)
+
+
 # ---------------------------------------------------------------------------
 # sweep driver + reference stream runner
 # ---------------------------------------------------------------------------
