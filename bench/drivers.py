@@ -1,5 +1,6 @@
-"""The two ways a cell drives the program, chosen by its traffic mix's
-``entry``:
+"""The drivers of the built-in entries, and the bases that new entries
+extend.  A traffic mix's ``entry`` names a file ``bench/entries/<entry>.py``
+whose ``DRIVER`` is one of these classes or a subclass:
 
 * ``simulate``: the evaluator's fleet run.  ``repro.api.simulate`` of the
   mix's policies over fleets of groups made from the seed, back to back
@@ -14,6 +15,16 @@ After the window each driver compares a sample of what the timed calls
 produced, drawn from the seed, with ``bench/reference.py`` and returns
 the numbers compared.  ``arith`` other than float64 puts the reference in
 the program's place, computed in that precision: the control.
+
+``Simulate`` has a seam wherever a configuration's semantics may differ,
+each a short method or class attribute that a subclass overrides:
+``call_kwargs`` (program inputs beyond the fleet), ``FIELDS`` (the
+result fields compared), ``reference_policy`` (the reference of a policy
+name), ``reference_run`` (the reference over the checked groups; its
+keywords override the configuration's twin parameters) and
+``extra_numbers`` (numbers compared beyond the default ones).  Each
+driver's ``small`` gives its cells the size of the CPU tests in
+``bench/tests``, which a subclass inherits.
 """
 from __future__ import annotations
 
@@ -42,10 +53,26 @@ def _gap(got: np.ndarray, want: np.ndarray) -> float:
 
 
 class Simulate:
-    """``api.simulate`` of fleets of consumer groups."""
+    """``api.simulate`` of fleets of consumer groups, on ``devices`` (by
+    default every device JAX sees); ``FleetRunner`` shards the groups
+    over them."""
 
-    def __init__(self, config: dict, mix: dict, seed: int):
+    FIELDS = ("lag_total", "lag_max", "consumers", "migrations",
+              "unreadable")
+    """The per-step result fields kept from each call and compared."""
+
+    @classmethod
+    def small(cls, config: dict, mix: dict) -> Tuple[dict, dict]:
+        """The cell at the size of the CPU tests: one group a family, 16
+        steps, two fleets, at most 24 partitions."""
+        n = min(int(config["partitionsPerTopic"]), 24)
+        return ({**config, "partitionsPerTopic": n},
+                {**mix, "groups_per_family": 1, "steps": 16, "fleets": 2,
+                 "check_per_family": 1})
+
+    def __init__(self, config: dict, mix: dict, seed: int, devices=None):
         self.config, self.mix, self.seed = config, mix, int(seed)
+        self.devices = None if devices is None else tuple(devices)
         self.n = int(config["partitionsPerTopic"])
         self.steps = int(mix["steps"])
         self.policies = tuple(mix["policies"])
@@ -76,7 +103,7 @@ class Simulate:
         import jax
 
         from repro import api
-        from repro.fleet import FleetRunner
+        from repro.fleet import FleetConfig, FleetRunner
 
         class KeepingRunner(FleetRunner):
             """The default runner; it also keeps its last raw result, whose
@@ -87,7 +114,7 @@ class Simulate:
                 return self.last
 
         self.api = api
-        self.runner = KeepingRunner()
+        self.runner = KeepingRunner(FleetConfig(devices=self.devices))
         self.cfg = self.lag_config()
         n_fleets = int(self.mix.get("fleets", 1))
         self.fleets = []
@@ -109,18 +136,24 @@ class Simulate:
         self.outputs.clear()
         self.calls = 0
 
+    def call_kwargs(self, k: int) -> dict:
+        """Keyword arguments that a call passes to ``api.simulate`` for
+        fleet ``k``, beyond the fleet, its mask, the policies and the
+        config: none by default."""
+        return {}
+
     def call(self, i: int) -> None:
         k = i % len(self.fleets)
         sp, ac = self.fleets[k]
         self.api.simulate(sp, policies=self.policies, active=ac,
-                          config=self.cfg, fleet=self.runner)
+                          config=self.cfg, fleet=self.runner,
+                          **self.call_kwargs(k))
         res = self.runner.last
         keep = [g for kk, g in self.checked if kk == k]
         if keep:
             self.outputs.append({"fleet": k, **{
                 f: np.stack([getattr(res, f)[g] for g in keep], axis=1)
-                for f in ("lag_total", "lag_max", "consumers", "migrations",
-                          "unreadable")}})
+                for f in self.FIELDS}})
         self.calls += 1
 
     def work_per_call(self) -> int:
@@ -150,36 +183,52 @@ class Simulate:
 
     # -- the comparison ---------------------------------------------------------
 
-    def _reference(self, arith: ref.Arith) -> Dict[tuple, Dict]:
+    def reference_policy(self, name: str, arith: ref.Arith):
+        """The reference object of policy ``name``, for ``reference_run``."""
         tw = self.config["twin"]
         cp = self.config.get("control_plane")
+        if name in ref.PACKERS:
+            return ref.PackerPolicy(name, float(tw["capacity"]), arith)
+        if name == "KEDA_LAG_REAL":
+            return ref.KedaLag(
+                n=self.n, lag_threshold=float(tw["lag_threshold"]),
+                patience=int(tw.get("scale_down_patience", 3)),
+                poll=cp["polling_interval"],
+                obs_delay=cp["observation_delay"],
+                act_delay=cp["actuation_delay"],
+                cooldown=cp["cooldown_period"],
+                min_replicas=cp["min_replicas"],
+                max_replicas=cp["max_replicas"],
+                warmup=cp["warmup_steps"], ar=arith)
+        raise ValueError(f"no reference for policy {name!r}")
+
+    def reference_run(self, k: int, groups: List[int], policy,
+                      arith: ref.Arith, **twin) -> Dict[str, np.ndarray]:
+        """The reference of ``policy`` over fleet ``k``'s ``groups``, with
+        the configuration's twin parameters as ``twin`` overrides them:
+        per step ``[B, T]`` trajectories of at least ``FIELDS``."""
+        tw = {**self.config["twin"], **twin}
+        sp, ac = self.fleets[k]
+        return ref.twin(np.asarray(sp)[groups], np.asarray(ac)[groups],
+                        policy, dt=float(tw["dt"]),
+                        capacity=float(tw["capacity"]),
+                        migration_steps=int(tw["migration_steps"]), ar=arith)
+
+    def _reference(self, arith: ref.Arith) -> Dict[tuple, Dict]:
         out = {}
         for k in sorted({kk for kk, _ in self.checked}):
             groups = [g for kk, g in self.checked if kk == k]
-            sp, ac = self.fleets[k]
-            rates = np.asarray(sp)[groups]
-            act = np.asarray(ac)[groups]
             for p in self.policies:
-                if p in ref.PACKERS:
-                    pol = ref.PackerPolicy(p, float(tw["capacity"]), arith)
-                elif p == "KEDA_LAG_REAL":
-                    pol = ref.KedaLag(
-                        n=self.n, lag_threshold=float(tw["lag_threshold"]),
-                        patience=int(tw.get("scale_down_patience", 3)),
-                        poll=cp["polling_interval"],
-                        obs_delay=cp["observation_delay"],
-                        act_delay=cp["actuation_delay"],
-                        cooldown=cp["cooldown_period"],
-                        min_replicas=cp["min_replicas"],
-                        max_replicas=cp["max_replicas"],
-                        warmup=cp["warmup_steps"], ar=arith)
-                else:
-                    raise ValueError(f"no reference for policy {p!r}")
-                out[(k, p)] = ref.twin(
-                    rates, act, pol, dt=float(tw["dt"]),
-                    capacity=float(tw["capacity"]),
-                    migration_steps=int(tw["migration_steps"]), ar=arith)
+                out[(k, p)] = self.reference_run(
+                    k, groups, self.reference_policy(p, arith), arith)
         return out
+
+    def extra_numbers(self, pairs: List[tuple]) -> Dict[str, float]:
+        """Numbers compared beyond the default ones, from ``pairs`` of
+        ``(fleet, policy, got, want)`` (``FIELDS`` to ``[B, T]`` arrays),
+        one pair for each call's checked groups and policy; each number is
+        lower-is-better and has a limit in the mix.  None by default."""
+        return {}
 
     def numbers(self, control: Optional[ref.Arith] = None
                 ) -> Tuple[Dict[str, float], int]:
@@ -192,6 +241,7 @@ class Simulate:
         gaps = {"lag_gap": 0.0, "lag_max_gap": 0.0}
         clamp = bad = 0
         cp = self.config.get("control_plane")
+        pairs = []
         for out in self.outputs:
             k = out["fleet"]
             wrong = off["decisions_off"] + off["unreadable_off"]
@@ -200,7 +250,8 @@ class Simulate:
                 if got_ref is not None:
                     g = got_ref[(k, p)]
                 else:
-                    g = {f: out[f][pi] for f in w}
+                    g = {f: out[f][pi] for f in self.FIELDS}
+                pairs.append((k, p, g, w))
                 off["decisions_off"] += int(np.sum(
                     (g["consumers"] != w["consumers"])
                     | (g["migrations"] != w["migrations"])))
@@ -215,16 +266,23 @@ class Simulate:
                     clamp += int(np.sum((c < cp["min_replicas"])
                                         | (c > cp["max_replicas"])))
             bad += int(off["decisions_off"] + off["unreadable_off"] > wrong)
-        nums = {**off, **gaps}
+        nums = {**off, **gaps, **self.extra_numbers(pairs)}
         if cp is not None:
             nums["clamp_off"] = clamp
         return nums, bad
 
 
 class Pack:
-    """``api.pack`` decisions for groups served in turn, closed loop."""
+    """``api.pack`` decisions for groups served in turn, closed loop, on
+    JAX's default device: the first of a cell's ``devices``."""
 
-    def __init__(self, config: dict, mix: dict, seed: int):
+    @classmethod
+    def small(cls, config: dict, mix: dict) -> Tuple[dict, dict]:
+        """The cell at the size of the CPU tests: 40 steps, 80 decisions
+        checked."""
+        return config, {**mix, "steps": 40, "check_decisions": 80}
+
+    def __init__(self, config: dict, mix: dict, seed: int, devices=None):
         self.config, self.mix, self.seed = config, mix, int(seed)
         self.n = int(config["partitionsPerTopic"])
         self.algorithm = mix["algorithm"]
@@ -323,5 +381,3 @@ class Pack:
             bad += int(sum(nums.values()) > before)
         return nums, bad
 
-
-DRIVERS = {"simulate": Simulate, "pack": Pack}

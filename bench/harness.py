@@ -2,10 +2,20 @@
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
 its configuration in ``bench/configs/<config>.json``, its traffic mix in
-``bench/traffic/<traffic>.json`` (whose ``entry`` picks the driver of
-``bench/drivers.py``), and each per-layer metric's reader in
-``bench/layers/<metric>.py``.  Adding a cell takes new files and entries
-only.
+``bench/traffic/<traffic>.json``, the driver of the mix's ``entry`` in
+``bench/entries/<entry>.py`` (its ``DRIVER``), and each per-layer
+metric's reader in ``bench/layers/<metric>.py``.  Adding a cell takes
+new files and entries only.
+
+The classes of ``bench/drivers.py`` are the drivers of the built-in
+entries and the bases that a new entry extends.  A configuration whose
+semantics differ (another program input, another policy, another
+reference) brings an entry file whose ``DRIVER`` subclasses
+``drivers.Simulate`` and overrides its seams: ``call_kwargs``,
+``FIELDS``, ``reference_policy``, ``reference_run`` and
+``extra_numbers``.  A cell's plain reference is its entry file together
+with what that file imports: ``bench/reference.py`` for the built-in
+entries, and for a new entry whatever it brings beside.
 """
 from __future__ import annotations
 
@@ -47,6 +57,10 @@ def layer_path(name: str, root: str = ROOT) -> str:
     return os.path.join(root, "bench", "layers", f"{name}.py")
 
 
+def entry_path(name: str, root: str = ROOT) -> str:
+    return os.path.join(root, "bench", "entries", f"{name}.py")
+
+
 def cell(name: str, root: str = ROOT) -> Tuple[dict, dict, dict, dict]:
     """``(spec, workload, config, mix)`` of the cell called ``name``."""
     s = spec(root)
@@ -70,16 +84,36 @@ def metrics_of(s: dict, workload: str) -> Tuple[List[dict], List[dict]]:
     return e2e, layer
 
 
+def _module(path: str, prefix: str, name: str):
+    mod_name = prefix + name.replace(".", "_").replace("-", "_")
+    sp = importlib.util.spec_from_file_location(mod_name, path)
+    if sp is None:
+        return None
+    mod = importlib.util.module_from_spec(sp)
+    sp.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str, root: str = ROOT):
     """The ``read(ctx)`` function of ``bench/layers/<metric>.py``."""
     path = layer_path(metric, root)
-    mod_name = "bench_layer_" + metric.replace(".", "_").replace("-", "_")
-    sp = importlib.util.spec_from_file_location(mod_name, path)
-    if sp is None:
+    mod = _module(path, "bench_layer_", metric)
+    if mod is None:
         raise BenchError(f"no reader for per-layer metric {metric!r}: {path}")
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
     return mod.read
+
+
+def driver(entry: str, root: str = ROOT):
+    """The ``DRIVER`` class of ``bench/entries/<entry>.py``, called as
+    ``DRIVER(config, mix, seed, devices)``."""
+    path = entry_path(entry, root)
+    if not os.path.isfile(path):
+        folder = os.path.dirname(path)
+        have = sorted(f[:-3] for f in os.listdir(folder)
+                      if f.endswith(".py")) if os.path.isdir(folder) else []
+        raise BenchError(f"no driver for entry {entry!r}: {path} is "
+                         f"missing; bench/entries has {have}")
+    return _module(path, "bench_entry_", entry).DRIVER
 
 
 def peaks(device_kind: str, root: str = ROOT) -> dict:
@@ -175,18 +209,17 @@ def memory_peak(devices) -> int:
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              t_start: float, devices, root: str = ROOT,
-             driver=None) -> Tuple[dict, Dict[str, Tuple[float, float]]]:
+             drv=None) -> Tuple[dict, Dict[str, Tuple[float, float]]]:
     """One run of one cell: ``(result line, {number: (value, limit)})``.
 
-    ``driver`` replaces the cell's driver object (the tests plant faults
+    ``drv`` replaces the cell's driver object (the tests plant faults
     this way); ``devices`` are the devices the cell runs on."""
-    from bench.drivers import DRIVERS
     from repro.telemetry.spans import default_tracer
 
     s, wl, config, mix = cell(workload, root)
     e2e, per_layer = metrics_of(s, workload)
-    drv = driver if driver is not None else DRIVERS[mix["entry"]](
-        config, mix, seed)
+    if drv is None:
+        drv = driver(mix["entry"], root)(config, mix, seed, devices)
     prog_spans = default_tracer()
     n0 = len(prog_spans.records())
     drv.setup()
@@ -220,6 +253,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             shutil.rmtree(tmp, ignore_errors=True)
     nums, bad = drv.numbers()
     limits = mix["limits"]
+    missing = sorted(set(nums) - set(limits))
+    if missing:
+        raise BenchError(f"the mix gives no limit for {missing}")
     compared = {k: (float(v), float(limits[k])) for k, v in nums.items()}
     correct = all(v <= lim for v, lim in compared.values())
 

@@ -28,12 +28,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
     from bench import harness
-    from bench.drivers import DRIVERS
     from bench.reference import PRECISIONS
 
     _, wl, config, mix = harness.cell(args.workload)
     try:
-        harness.chips_present(int(wl["chips"]))
+        devices = harness.chips_present(int(wl["chips"]))[:int(wl["chips"])]
     except harness.BenchError as e:
         print(f"readings: {e}", file=sys.stderr)
         return 2
@@ -41,7 +40,7 @@ def main(argv=None) -> int:
     seeds = [int(s) for s in args.seeds.split(",") if s]
     control = {int(s) for s in args.control_seeds.split(",") if s}
     for seed in seeds + sorted(control - set(seeds)):
-        drv = DRIVERS[mix["entry"]](config, mix, seed)
+        drv = harness.driver(mix["entry"])(config, mix, seed, devices)
         drv.setup()
         t0 = time.perf_counter()
         drv.window(args.seconds, harness.WindowTracer(None, 0))

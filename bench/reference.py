@@ -284,10 +284,12 @@ class KedaLag:
 
 
 class PackerPolicy:
-    """A packer repacking every step with the last assignment as ``prev``."""
+    """A packer repacking every step with the last assignment as ``prev``:
+    ``PACKERS[name]``, or ``fn`` of the same signature where given."""
 
-    def __init__(self, name: str, cap: float, ar: Arith):
-        self.fn, self.cap, self.ar = PACKERS[name], cap, ar
+    def __init__(self, name: str, cap: float, ar: Arith,
+                 fn: Optional[Callable] = None):
+        self.fn, self.cap, self.ar = fn or PACKERS[name], cap, ar
 
     def init(self, b: int):
         pass

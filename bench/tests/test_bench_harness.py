@@ -1,6 +1,7 @@
 """The harness's data: every cell of ``BENCHMARK.json`` finds its
-configuration, traffic mix and per-layer readers by name, the file keeps
-the contract's shape, and ``bench/run.py`` refuses to run without a TPU."""
+configuration, traffic mix, entry driver and per-layer readers by name,
+the file keeps the contract's shape, and ``bench/run.py`` refuses to run
+without a TPU."""
 import json
 import os
 import re
@@ -47,7 +48,7 @@ def test_workload_finds_its_files(wl):
         assert NAME.match(wl[key])
     assert TEXT.match(wl["why"]) and wl["chips"] in (1, 4)
     _, _, config, mix = harness.cell(wl["name"])
-    assert mix["entry"] in ("simulate", "pack")
+    assert isinstance(harness.driver(mix["entry"]), type)
     assert set(mix["limits"]) and all(
         isinstance(v, (int, float)) for v in mix["limits"].values())
     e2e, layer = harness.metrics_of(SPEC, wl["name"])
@@ -87,6 +88,41 @@ def test_peaks_by_device_kind():
     assert v5e["hbm_bytes_per_s"] == 819e9 and v5e["source"]
     with pytest.raises(harness.BenchError, match="no peaks"):
         harness.peaks("cpu")
+
+
+@pytest.mark.parametrize("entry", ["simulate", "pack"])
+def test_builtin_entry_from_its_file(entry):
+    from bench import drivers
+
+    path = harness.entry_path(entry)
+    assert path.endswith(os.path.join("bench", "entries", f"{entry}.py"))
+    assert os.path.isfile(path)
+    assert harness.driver(entry) is getattr(drivers, entry.capitalize())
+
+
+def test_missing_entry_names_its_path():
+    with pytest.raises(harness.BenchError) as e:
+        harness.driver("no-such-entry")
+    assert harness.entry_path("no-such-entry") in str(e.value)
+    assert "'pack'" in str(e.value) and "'simulate'" in str(e.value)
+
+
+X4_METRICS = ("eval_rate", "setup_s", "host_ms.sim", "device_us_per_step.sim",
+              "idle.sim", "compile_s", "read_ms.sim", "post_ms.sim")
+
+
+def test_four_chip_cell_loads():
+    _, wl, config, mix = harness.cell("omb100-sim-packers-x4")
+    assert wl["chips"] == 4 and wl["config"] == "omb-100p"
+    assert mix["entry"] == "simulate"
+    b = len(mix["families"]) * mix["groups_per_family"]
+    assert b == 256 and b % wl["chips"] == 0
+    assert (mix["steps"], mix["fleets"]) == (100, 4)
+    e2e, layer = harness.metrics_of(SPEC, wl["name"])
+    reported = e2e + layer
+    assert sorted(m["name"] for m in reported) == sorted(X4_METRICS)
+    for m in reported:
+        assert wl["name"] in m.get("workloads", [wl["name"]])
 
 
 def test_unknown_workload_is_named():
