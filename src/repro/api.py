@@ -246,6 +246,9 @@ class SimulateOutcome:
     #: per-scenario decoded ``Incident`` lists (``index == (policy,)``)
     #: when ``telemetry.alerts`` is on
     incidents: Optional[List[List[Any]]] = None
+    #: i32[P, B, T] active partitions left unread by a crashed consumer,
+    #: when the call passed a ``crash`` schedule
+    orphaned: Optional[np.ndarray] = None
     schema_version: int = API_VERSION
 
 
@@ -433,8 +436,8 @@ def sweep(traces, capacity: float = 1.0, *,
 
 @traced("api.simulate")
 def simulate(traces, *, policies: Optional[Sequence[str]] = None,
-             config=None, active=None, fleet=None, control_plane=None,
-             **cfg_overrides) -> SimulateOutcome:
+             config=None, active=None, crash=None, fleet=None,
+             control_plane=None, **cfg_overrides) -> SimulateOutcome:
     """Closed-loop lag twin over ``traces`` f32[B, T, N]: backlog, shared
     drain budgets and migration downtime per policy, reduced to SLO
     metrics (violation fraction, peak lag, time-to-drain,
@@ -463,7 +466,31 @@ def simulate(traces, *, policies: Optional[Sequence[str]] = None,
     and control-plane-wrapped configs raise ``FusedPathError``;
     reactive baselines, ``n > FUSED_MAX_PARTITIONS`` and per-step frame
     recording (an O(T) surface the fused engine does not emit) fall
-    back to the unfused scan per policy."""
+    back to the unfused scan per policy.
+
+    ``crash`` (bool[B, T, 2N + 2], optional) is a schedule of hard
+    consumer failures over the consumer ids the policies name:
+    ``crash[b, t, c]`` kills consumer ``c`` of group ``b`` at the start
+    of step ``t``, with no ``LeaveGroup``.  At each step, in order:
+
+    1. expel: a consumer dead for ``orphan_steps`` steps (a config knob,
+       the session timeout in steps, default 15) loses its partitions,
+       which go unowned, and its id is free again; placing them again is
+       no move;
+    2. crash: a scheduled id that an active partition holds and that is
+       alive dies for ``orphan_steps`` steps, and every partition on it is
+       orphaned for as long (a crash of an unused or dead id does
+       nothing);
+    3. the policy's step and migration downtime, as without a schedule;
+    4. orphaned partitions, those inside their window (moved or not) and
+       those the policy puts on a dead id, are unread and count in
+       ``unreadable``; ``.orphaned`` counts the active ones per step;
+    5. the drain.
+
+    ``consumers`` stays the policy's count: a dead pod is billed until it
+    is expelled.  A schedule of the wrong shape or dtype raises a
+    ``ValueError`` that names both shapes; a run with one takes the
+    per-step scan (no fused path)."""
     import dataclasses as _dc
 
     from repro.lagsim import ControlPlaneConfig as _CPC
@@ -481,7 +508,8 @@ def simulate(traces, *, policies: Optional[Sequence[str]] = None,
     cfg.resolve(traces.shape[-1] if hasattr(traces, "shape")
                 else np.asarray(traces).shape[-1])  # fail fast on bad knobs
     runner = fleet if fleet is not None else default_fleet()
-    res = runner.simulate(tuple(policies), traces, cfg, active=active)
+    res = runner.simulate(tuple(policies), traces, cfg, active=active,
+                          crash=crash)
     with span("sim.summarize"):
         st = res.stacked()
         metrics = {k: np.asarray(v)
@@ -501,7 +529,8 @@ def simulate(traces, *, policies: Optional[Sequence[str]] = None,
                            consumers=st["consumers"],
                            migrations=st["migrations"],
                            telemetry=res.telemetry,
-                           sketches=sketches, incidents=incidents)
+                           sketches=sketches, incidents=incidents,
+                           orphaned=st.get("orphaned"))
 
 
 @traced("api.optimize")

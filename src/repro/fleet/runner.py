@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.jaxpack import _sweep_streams_impl
-from repro.lagsim.engine import LagSimConfig, _sweep_impl
+from repro.lagsim.engine import LagSimConfig, _sweep_impl, check_crash
 from repro.lagsim.metrics import slo_summary
 from repro.telemetry.alerts import (AlertConfig, AlertState, Incident,
                                     decode_incidents, incident_counts,
@@ -120,6 +120,9 @@ class FleetLagResult:
     consumers: List[np.ndarray]     # i32[P, T_i]
     migrations: List[np.ndarray]    # i32[P, T_i]
     unreadable: List[np.ndarray]    # i32[P, T_i]
+    #: per-scenario orphaned-partition counts ``i32[P, T_i]``, present iff
+    #: the run carried a crash schedule
+    orphaned: Optional[List[np.ndarray]] = None
     #: per-scenario recorder frames (channels ``[P, T_i, K]``), present
     #: iff the config's ``TelemetryConfig`` is on; decode each with
     #: ``EventStream.from_frame``
@@ -158,9 +161,12 @@ class FleetLagResult:
                                 dt=self.dt)
 
     def stacked(self) -> Dict[str, np.ndarray]:
-        """Stack a uniform-``T`` fleet into ``[P, B, T]`` arrays."""
+        """Stack a uniform-``T`` fleet into ``[P, B, T]`` arrays
+        (``orphaned`` too, when the run carried a crash schedule)."""
+        names = _TRAJ_FIELDS + (() if self.orphaned is None
+                                else ("orphaned",))
         return {name: np.stack(getattr(self, name), axis=1)
-                for name in _TRAJ_FIELDS}
+                for name in names}
 
     def summarize(self, cfg: LagSimConfig,
                   stacked: Optional[Dict[str, np.ndarray]] = None
@@ -384,24 +390,32 @@ class FleetRunner:
         return groups
 
     def _pad_and_stack(self, members, tb: int, nb: int, use_mask: bool,
-                       n_dev: int):
-        """-> (speeds [Bp, tb, nb], active [Bp, tb, nb] | None)."""
-        sps, acs = [], []
-        for _, sp, ac in members:
+                       n_dev: int, crashes=None):
+        """-> (speeds [Bp, tb, nb], active [Bp, tb, nb] | None, crash
+        [Bp, tb, 2nb + 2] | None); ``crashes`` are the scenarios' crash
+        schedules by input index, padded with no crash on every axis."""
+        sps, acs, crs = [], [], []
+        for idx, sp, ac in members:
             t, n = sp.shape
             pad = ((0, tb - t), (0, nb - n))
             sps.append(jnp.pad(sp, pad))
             if use_mask:
                 ac = jnp.ones((t, n), bool) if ac is None else ac
                 acs.append(jnp.pad(ac, pad))        # pads with False
+            if crashes is not None:
+                crs.append(jnp.pad(crashes[idx], ((0, tb - t),
+                                                  (0, 2 * (nb - n)))))
         n_pad = (-len(sps)) % n_dev
         for _ in range(n_pad):          # dummy scenarios for the shard grid
             sps.append(jnp.zeros((tb, nb), jnp.float32))
             if use_mask:
                 acs.append(jnp.zeros((tb, nb), bool))
+            if crashes is not None:
+                crs.append(jnp.zeros((tb, 2 * nb + 2), bool))
         speeds = jnp.stack(sps)
         active = jnp.stack(acs) if use_mask else None
-        return speeds, active
+        crash = jnp.stack(crs) if crashes is not None else None
+        return speeds, active, crash
 
     def _uniform_batch(self, scenarios, active, n_dev: int):
         """Fast-path probe: an already-stacked ``f32[B, T, N]`` batch that
@@ -427,16 +441,16 @@ class FleetRunner:
         self._bucket_counts[(t, n)] = self._bucket_counts.get((t, n), 0) + b
         return sp, ac
 
-    def _device_put(self, speeds, active):
+    def _device_put(self, *arrays):
+        """The batch-leading ``arrays`` sharded over the ``("batch",)``
+        mesh (``None`` stays ``None``)."""
         devices = self._devices()
         if not self.config.shard or len(devices) <= 1:
-            return speeds, active
+            return arrays
         mesh = Mesh(np.asarray(devices), ("batch",))
         sharding = NamedSharding(mesh, PartitionSpec("batch"))
-        speeds = jax.device_put(speeds, sharding)
-        if active is not None:
-            active = jax.device_put(active, sharding)
-        return speeds, active
+        return tuple(None if a is None else jax.device_put(a, sharding)
+                     for a in arrays)
 
     def _n_dev(self) -> int:
         devices = self._devices()
@@ -487,8 +501,8 @@ class FleetRunner:
         out_rs: List[Optional[np.ndarray]] = [None] * len(items)
         out_migs: List[Optional[np.ndarray]] = [None] * len(items)
         for (tb, nb, use_mask), members in self._group(items).items():
-            speeds, act = self._pad_and_stack(members, tb, nb, use_mask,
-                                              n_dev)
+            speeds, act, _ = self._pad_and_stack(members, tb, nb, use_mask,
+                                                 n_dev)
             bins, rs, migs = self._run_sweep(algorithms, speeds, act,
                                              capacity, tb, nb)
             for slot, (idx, sp, _) in enumerate(members):
@@ -502,15 +516,20 @@ class FleetRunner:
     _SIM_FIELDS = _TRAJ_FIELDS
 
     def _run_sim(self, policies, speeds, act, rcfg, tb: int, nb: int,
-                 valid=None):
-        speeds, act = self._device_put(speeds, act)
+                 valid=None, crash=None):
+        speeds, act, crash = self._device_put(speeds, act, crash)
         # `valid is not None` is part of the key: the gated program takes
         # a third operand, so it must never share an executable with the
-        # ungated one even at identical shapes
+        # ungated one even at identical shapes; so is a crash schedule
         key = ("simulate", policies, tb, nb, act is not None,
-               valid is not None, rcfg, speeds.shape[0])
+               valid is not None, crash is not None, rcfg, speeds.shape[0])
         bucket = f"{tb}x{nb}"
-        if valid is None:
+        if crash is not None:
+            args = (speeds, act, valid, crash)
+            build = lambda: jax.jit(
+                lambda tr, ac, va, cr: _sweep_impl(policies, tr, rcfg, ac,
+                                                   va, cr))
+        elif valid is None:
             args = (speeds, act)
             build = lambda: jax.jit(
                 lambda tr, ac: _sweep_impl(policies, tr, rcfg, ac))
@@ -520,23 +539,22 @@ class FleetRunner:
                 lambda tr, ac, va: _sweep_impl(policies, tr, rcfg, ac, va))
         fn = self._compiled(key, build, args, bucket)
         res = self._dispatch(key, fn, args, bucket)
+        fields = self._SIM_FIELDS + (() if crash is None else ("orphaned",))
         with _span("fleet.read") as counts:
-            arrays = {f: np.asarray(getattr(res, f))
-                      for f in self._SIM_FIELDS}
-            tele = res.telemetry
-            if tele is not None:
-                tele = TelemetryFrame(
-                    channels=np.asarray(tele.channels),   # [P, B, T, K]
-                    steps=np.asarray(tele.steps),         # [P, B, T]
-                    count=np.asarray(tele.count),         # [P, B]
-                    names=tele.names)
-            to_np = lambda obj: (None if obj is None else
-                                 jax.tree_util.tree_map(np.asarray, obj))
-            sk, inc = to_np(res.sketch), to_np(res.incidents)
+            out = ({f: getattr(res, f) for f in fields}, res.telemetry,
+                   res.sketch, res.incidents)
+            # every leaf's copy starts before the first is awaited, so the
+            # ~30 reads share one round trip instead of one each
+            for leaf in jax.tree_util.tree_leaves(out):
+                leaf.copy_to_host_async()
+            arrays, tele, sk, inc = jax.tree_util.tree_map(np.asarray, out)
             if counts is not None:
                 read = jax.tree_util.tree_leaves((arrays, tele, sk, inc))
                 counts.update(arrays=len(read),
                               bytes=sum(a.nbytes for a in read))
+                if crash is not None:
+                    # orphaned partition-steps of the call (padding has none)
+                    counts.update(orphaned=int(arrays["orphaned"].sum()))
         return arrays, tele, sk, inc
 
     @staticmethod
@@ -567,7 +585,7 @@ class FleetRunner:
 
     def simulate(self, policies: Sequence[str], scenarios,
                  cfg: LagSimConfig = LagSimConfig(), *,
-                 active=None,
+                 active=None, crash=None,
                  progress: Optional[Callable[[FleetProgress], None]] = None
                  ) -> FleetLagResult:
         """Closed-loop lag twin over a fleet of scenarios.
@@ -591,16 +609,41 @@ class FleetRunner:
         and come back as per-scenario states; padded bucket steps are
         gated out of their updates, so padding stays exact for them too.
 
+        ``crash`` is a schedule of hard consumer failures
+        (``repro.lagsim.engine._simulate``): ``bool[B, T, 2N + 2]`` for a
+        stacked batch, or one ``bool[T_i, 2N_i + 2]`` per scenario for a
+        sequence.  It is padded with no crash on the batch, step and
+        consumer-id axes, sharded like ``active``, and the result then
+        carries ``orphaned``.
+
         ``progress`` (optional, host-side) is called after each bucket
         group with a :class:`FleetProgress` snapshot -- merged sketch
         summary and cumulative incident counts so far; this is what
         ``examples/live_dashboard.py`` streams.
         """
         with _span("fleet.simulate", policies=len(policies)):
-            return self._simulate(policies, scenarios, cfg, active, progress)
+            return self._simulate(policies, scenarios, cfg, active, progress,
+                                  crash)
+
+    @staticmethod
+    def _crash_list(crash, items) -> Optional[List[jax.Array]]:
+        """Per-scenario crash schedules, each checked against its
+        scenario's ``(T_i, N_i)``."""
+        if crash is None:
+            return None
+        if hasattr(crash, "ndim") and crash.ndim == 3:
+            crash = [crash[b] for b in range(crash.shape[0])]
+        crash = list(crash)
+        if len(crash) != len(items):
+            raise ValueError(
+                f"crash holds {len(crash)} schedules for {len(items)} "
+                f"scenarios; give one per scenario")
+        for cr, (sp, _) in zip(crash, items):
+            check_crash(cr, sp.shape)
+        return [jnp.asarray(cr) for cr in crash]
 
     def _simulate(self, policies, scenarios, cfg: LagSimConfig,
-                  active, progress=None) -> FleetLagResult:
+                  active, progress=None, crash=None) -> FleetLagResult:
         if cfg.telemetry is not None and cfg.telemetry.ring is not None:
             raise ValueError(
                 "TelemetryConfig.ring is not supported through FleetRunner: "
@@ -615,14 +658,17 @@ class FleetRunner:
         if fast is not None:
             speeds, act = fast
             b, t, n = speeds.shape
+            if crash is not None:
+                crash = jnp.asarray(crash)
+                check_crash(crash, speeds.shape)
             rcfg = cfg.resolve(n)
             arrays, tele, sk, inc = self._run_sim(policies, speeds, act,
-                                                  rcfg, t, n)
+                                                  rcfg, t, n, crash=crash)
             sk_cfg = None if rcfg.telemetry is None else rcfg.telemetry.sketch
             with _span("fleet.unpack", scenarios=b):
                 result = FleetLagResult(policies=policies, **{
                     f: [arrays[f][:, i] for i in range(b)]
-                    for f in self._SIM_FIELDS},
+                    for f in arrays},
                     telemetry=None if tele is None else [
                         self._scenario_frame(tele, i, t) for i in range(b)],
                     sketch=None if sk is None else [
@@ -635,9 +681,11 @@ class FleetRunner:
                 progress(self._progress_snapshot(result, b, b, f"{t}x{n}"))
             return result
         items = self._normalize(scenarios, active)
+        crashes = self._crash_list(crash, items)
         obs_on = self._obs_on(cfg)
+        fields = self._SIM_FIELDS + (() if crash is None else ("orphaned",))
         outs: Dict[str, List[Optional[np.ndarray]]] = {
-            f: [None] * len(items) for f in self._SIM_FIELDS}
+            f: [None] * len(items) for f in fields}
         tele_out: List[Optional[TelemetryFrame]] = [None] * len(items)
         sk_out: List[Optional[SketchState]] = [None] * len(items)
         sk_cfg_out: List[Optional[SketchConfig]] = [None] * len(items)
@@ -650,8 +698,8 @@ class FleetRunner:
         groups = self._group(items,
                              extra_key=lambda sp, ac: (cfg.resolve(sp.shape[1]),))
         for (tb, nb, use_mask, rcfg), members in groups.items():
-            speeds, act = self._pad_and_stack(members, tb, nb, use_mask,
-                                              n_dev)
+            speeds, act, cr = self._pad_and_stack(members, tb, nb, use_mask,
+                                                  n_dev, crashes)
             valid = None
             if obs_on:
                 # bool[B, T]: a scenario's true steps, False on T-padding
@@ -660,12 +708,12 @@ class FleetRunner:
                 rows += [np.zeros(tb, bool)] * (speeds.shape[0] - len(rows))
                 valid = jnp.asarray(np.stack(rows))
             arrays, tele, sk, inc = self._run_sim(policies, speeds, act,
-                                                  rcfg, tb, nb, valid)
+                                                  rcfg, tb, nb, valid, cr)
             sk_cfg = None if rcfg.telemetry is None else rcfg.telemetry.sketch
             with _span("fleet.unpack", scenarios=len(members)):
                 for slot, (idx, sp, _) in enumerate(members):
                     t = sp.shape[0]
-                    for f in self._SIM_FIELDS:
+                    for f in fields:
                         outs[f][idx] = arrays[f][:, slot, :t]
                     if tele is not None:
                         any_tele = True

@@ -20,6 +20,14 @@ programs.  Per step ``t``:
      water-filling; the fused Pallas kernel in
      ``kernels/lag_update.py`` implements the same update).
 
+A crash schedule (``crash``, bool[T, 2N + 2] over the consumer ids the
+policies name) adds hard consumer failures, with the semantics of the
+object loop's ``Controller._detect_failures`` under Kafka's
+``session.timeout.ms``: a crashed consumer sends no ``LeaveGroup``, so
+its partitions stay assigned to it, unread, until it is expelled
+``orphan_steps`` steps later (see ``expel_and_crash`` and
+``orphan_mask``).
+
 The recorded trajectories (total/max lag, consumers, migrations,
 unreadable partitions) feed the SLO metrics in ``metrics.py``.  A golden
 test cross-validates the twin against ``serving/simulation.py`` on a
@@ -76,6 +84,7 @@ class LagSimConfig:
     fused_kernel: bool = False        # fused path launches kernels/loop_fused
     control_plane: Optional[ControlPlaneConfig] = None  # scaler friction
     telemetry: Optional[TelemetryConfig] = None  # in-loop flight recorder
+    orphan_steps: int = 15            # session timeout of a crashed consumer
 
     @property
     def telemetry_on(self) -> bool:
@@ -108,6 +117,11 @@ class LagSimConfig:
             raise ValueError(
                 f"fused_steps must be >= 0 (0 disables the fused path), "
                 f"got {self.fused_steps}")
+        if int(self.orphan_steps) < 1:
+            raise ValueError(
+                f"orphan_steps must be >= 1 (the steps a crashed consumer "
+                f"keeps its partitions before it is expelled), got "
+                f"{self.orphan_steps}")
         if self.fused_kernel and not self.fused_steps:
             raise ValueError(
                 "fused_kernel=True requires fused_steps > 0: the megakernel "
@@ -149,6 +163,7 @@ class LagTrace:
     telemetry: Optional[TelemetryFrame] = None  # recorder frame [.., R, K]
     sketch: Optional[SketchState] = None    # streaming aggregators (O(1))
     incidents: Optional[AlertState] = None  # in-loop alert/incident state
+    orphaned: Optional[jax.Array] = None    # i32 orphans (crash schedule)
 
 
 @jax.tree_util.register_dataclass
@@ -165,6 +180,7 @@ class LagSweepResult:
     telemetry: Optional[TelemetryFrame] = None  # frame [P, B, R, K]
     sketch: Optional[SketchState] = None    # aggregators, leading [P, B]
     incidents: Optional[AlertState] = None  # alert state, leading [P, B]
+    orphaned: Optional[jax.Array] = None    # i32[P, B, T] (crash schedule)
 
     def for_policy(self, name: str) -> LagTrace:
         p = self.policies.index(name.upper())
@@ -174,7 +190,8 @@ class LagSweepResult:
                         self.migrations[p], self.unreadable[p],
                         telemetry=pick(self.telemetry),
                         sketch=pick(self.sketch),
-                        incidents=pick(self.incidents))
+                        incidents=pick(self.incidents),
+                        orphaned=pick(self.orphaned))
 
 
 def _check_rates_shape(rates, n: int, what: str, array_name: str) -> None:
@@ -188,10 +205,65 @@ def _check_rates_shape(rates, n: int, what: str, array_name: str) -> None:
             f"policy n = {n} partitions to pack; {what}")
 
 
+def check_crash(crash, rates_shape: Tuple[int, ...]) -> None:
+    """A crash schedule for rates of shape ``[..., T, N]`` is
+    ``bool[..., T, 2N + 2]``: one flag per step and consumer id.  Anything
+    else fails here, naming both shapes, and not deep inside the scan."""
+    *lead, t, n = rates_shape
+    want = (*lead, t, 2 * n + 2)
+    got = tuple(np.shape(crash))
+    dtype = np.dtype(getattr(crash, "dtype", np.asarray(crash).dtype))
+    if got != want or dtype != np.bool_:
+        raise ValueError(
+            f"crash schedule has shape {got} and dtype {dtype}, but rates of "
+            f"shape {tuple(rates_shape)} need bool{list(want)}: one flag per "
+            f"step and consumer id, over the 2N + 2 ids the policies name")
+
+
+def expel_and_crash(t, assign, crash_t, dead_until, orphan_until, act_t,
+                    orphan_steps: int):
+    """Steps 1 and 2 of a step ``t`` under a crash schedule, before the
+    policy sees the carried ``assign`` (i32[N]).
+
+    1. Expel: every consumer id whose session timeout ends at ``t``
+       (``dead_until == t``) loses its partitions, which go to ``NEG``,
+       and is alive again, free for reuse.  Placing them again is no move.
+    2. Crash: every id in ``crash_t`` (bool[M]) that an active partition's
+       assignment holds and that is alive dies for steps ``t .. t + S - 1``
+       (``S = orphan_steps``); every partition assigned to it is orphaned
+       for the same steps.  A crash of an id nobody holds, or of a dead
+       one, changes nothing.
+
+    Returns ``(assign, dead_until i32[M], orphan_until i32[N])``."""
+    ids = jnp.arange(dead_until.shape[0], dtype=jnp.int32)
+    owner = assign[:, None] == ids[None, :]             # [N, M]; NEG: none
+    expelled = jnp.any(owner & (dead_until == t)[None, :], axis=1)
+    assign = jnp.where(expelled, jnp.int32(NEG), assign)
+    owner = owner & ~expelled[:, None]
+    held = owner if act_t is None else owner & act_t[:, None]
+    dies = crash_t & jnp.any(held, axis=0) & (dead_until <= t)
+    until = t + jnp.int32(orphan_steps)
+    dead_until = jnp.where(dies, until, dead_until)
+    hit = jnp.any(owner & dies[None, :], axis=1)
+    return assign, dead_until, jnp.where(hit, until, orphan_until)
+
+
+def orphan_mask(t, new_assign, dead_until, orphan_until):
+    """Step 4: the partitions (bool[N]) unread at ``t`` for a crash: those
+    inside their orphan window, even if the policy moved them to a live
+    consumer (the revocation is never acknowledged), and those the new
+    assignment puts on a dead id."""
+    ids = jnp.arange(dead_until.shape[0], dtype=jnp.int32)
+    on_dead = jnp.any((new_assign[:, None] == ids[None, :])
+                      & (dead_until > t)[None, :], axis=1)
+    return (orphan_until > t) | on_dead
+
+
 def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
               cfg: LagSimConfig, active: Optional[jax.Array] = None,
               record_assign: bool = False,
-              valid: Optional[jax.Array] = None):
+              valid: Optional[jax.Array] = None,
+              crash: Optional[jax.Array] = None):
     """Unjitted core: ``trace`` f32[T, N] -> LagTrace of f32/i32[T].
 
     ``active`` (bool[T, N], optional) marks which partitions exist at each
@@ -219,9 +291,20 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
     optional, fleet-internal) gates sketch/alert updates on padded
     bucket steps so a padded run's observability state agrees with the
     direct run's (``repro.lagsim.metrics.agrees``).
+
+    ``crash`` (bool[T, 2N + 2], optional) is a schedule of hard consumer
+    failures: ``crash[t, c]`` kills consumer id ``c`` at the start of step
+    ``t``.  Each step then (1) expels the ids whose ``cfg.orphan_steps``
+    session timeout ends, (2) applies the step's crashes
+    (``expel_and_crash``), (3) runs the policy and migration downtime as
+    above, (4) makes the orphans (``orphan_mask``) unreadable and counts
+    them in ``unreadable`` and in the new output ``orphaned``, and (5)
+    drains.  ``consumers`` stays the policy's count: a dead pod is billed
+    until it is expelled.  Without a schedule none of this is traced.
     """
     n = trace.shape[1]
-    if cfg.fused_steps and fused_mode(policy, cfg, n) == "fused":
+    if cfg.fused_steps and fused_mode(policy, cfg, n,
+                                      crash=crash is not None) == "fused":
         # heuristic family under fused_steps: the multi-step fused path
         # (repro.lagsim.fused) replaces the per-step scan
         return simulate_fused(trace, initial_lag, policy, cfg, active=active,
@@ -269,6 +352,9 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
                                     cap_step, m=m, active=act_t)
 
     def step(carry, xs):
+        if crash is not None:
+            xs, crash_t = xs
+            tick, dead_until, orphan_until = carry[-1]
         lag, assign, down, pstate = carry[:4]
         ci = 4
         if ring_mode:
@@ -293,6 +379,10 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
                 rate_t, act_t, valid_t = xs
             produced = jnp.where(act_t, rate_t * jnp.float32(cfg.dt), 0.0)
         observed = lag + produced       # backlog a lag-reactive scaler sees
+        if crash is not None:
+            assign, dead_until, orphan_until = expel_and_crash(
+                tick, assign, crash_t, dead_until, orphan_until, act_t,
+                cfg.orphan_steps)
         if active is None:
             new_assign, n_active, pstate = policy_step(
                 rate_t, observed, assign, pstate)
@@ -313,6 +403,13 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
             readable = readable & ~storm
             storm_mask = storm & (new_assign >= 0)
             blocked = blocked | storm_mask
+        orphaned = None
+        if crash is not None:
+            orphaned = orphan_mask(tick, new_assign, dead_until, orphan_until)
+            readable = readable & ~orphaned
+            blocked = blocked | orphaned
+            if act_t is not None:
+                orphaned = orphaned & act_t
         new_lag = drain(lag, produced, new_assign, readable, act_t)
         unreadable = blocked if act_t is None else (blocked & act_t)
         ys = (jnp.sum(new_lag), jnp.max(new_lag),
@@ -325,11 +422,14 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
             vec, tele_names[0] = record_step(
                 tele, speeds=rate_t, new_lag=new_lag, moved=moved,
                 blocked=unreadable, storm=storm_mask, n_consumers=n_active,
-                act_t=act_t, capacity=cfg.capacity, pstate=pstate)
+                act_t=act_t, capacity=cfg.capacity, pstate=pstate,
+                orphaned=orphaned)
             if frames_on and not ring_mode:
                 ys = ys + (vec,)
         if record_assign:
             ys = ys + (new_assign,)
+        if crash is not None:
+            ys = ys + (jnp.sum(orphaned.astype(jnp.int32)),)
         new_carry = (new_lag, new_assign, down, pstate)
         if ring_mode:
             new_carry = new_carry + (tick + 1, ring_write(rbuf, tick, vec))
@@ -343,6 +443,8 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
                 tele.alerts, al, lag_total=ys[0], consumers=n_active,
                 unreadable=ys[4], storm_parts=storm_ct,
                 slo_lag=cfg.slo_lag, valid=valid_t),)
+        if crash is not None:
+            new_carry = new_carry + ((tick + 1, dead_until, orphan_until),)
         return new_carry, ys
 
     if active is None:
@@ -353,11 +455,14 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
               if valid is None
               else (trace.astype(jnp.float32), active.astype(bool),
                     valid.astype(bool)))
+    if crash is not None:
+        xs = (xs, crash.astype(bool))
     carry0 = (initial_lag.astype(jnp.float32), jnp.full(n, NEG, jnp.int32),
               jnp.zeros(n, jnp.int32), init(n))
     if tele is not None:
         pstate0 = carry0[3]
         full_names = tele.base_channels + (
+            ("orphaned",) if crash is not None else ()) + (
             tuple(pstate0.names) if isinstance(pstate0, CounterState) else ())
     if ring_mode:
         carry0 = carry0 + (jnp.int32(0), ring_init(tele, len(full_names)))
@@ -365,6 +470,10 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
         carry0 = carry0 + (sketch_init(tele.sketch, full_names),)
     if alerts_on:
         carry0 = carry0 + (alert_init(tele.alerts),)
+    if crash is not None:
+        # (tick, dead_until per consumer id, orphan_until per partition)
+        carry0 = carry0 + ((jnp.int32(0), jnp.full(m, -1, jnp.int32),
+                            jnp.zeros(n, jnp.int32)),)
     carry_end, ys = lax.scan(step, carry0, xs)
     tot, mx, cons, migs, unread = ys[:5]
     idx = 5
@@ -385,23 +494,26 @@ def _simulate(trace: jax.Array, initial_lag: jax.Array, policy: str,
     al_state = carry_end[ci] if alerts_on else None
     out = LagTrace(lag_total=tot, lag_max=mx, consumers=cons,
                    migrations=migs, unreadable=unread, telemetry=frame,
-                   sketch=sk_state, incidents=al_state)
+                   sketch=sk_state, incidents=al_state,
+                   orphaned=None if crash is None else ys[-1])
     return (out, ys[idx]) if record_assign else out
 
 
 @functools.partial(jax.jit,
                    static_argnames=("policy", "cfg", "record_assign"))
 def _simulate_jit(trace, initial_lag, policy: str, cfg: LagSimConfig,
-                  active=None, record_assign: bool = False, valid=None):
+                  active=None, record_assign: bool = False, valid=None,
+                  crash=None):
     return _simulate(trace, initial_lag, policy, cfg, active, record_assign,
-                     valid)
+                     valid, crash)
 
 
 def simulate_lag(trace: jax.Array, *, policy: str,
                  cfg: LagSimConfig = LagSimConfig(),
                  initial_lag: Optional[jax.Array] = None,
                  active: Optional[jax.Array] = None,
-                 record_assign: bool = False):
+                 record_assign: bool = False,
+                 crash: Optional[jax.Array] = None):
     """Run one policy over one stream ``f32[T, N]`` -> ``LagTrace`` of [T].
 
     ``initial_lag`` (f32[N], default zeros) seeds the per-partition backlog
@@ -410,6 +522,8 @@ def simulate_lag(trace: jax.Array, *, policy: str,
     masks partitions that do not exist at a step: unreadable and empty
     (see ``_simulate``).  ``record_assign=True`` returns
     ``(LagTrace, assigns i32[T, N])`` instead of the trace alone.
+    ``crash`` (bool[T, 2N + 2], optional) is a schedule of hard consumer
+    failures; the trace then carries ``orphaned`` (see ``_simulate``).
     """
     trace = jnp.asarray(trace)
     if trace.ndim != 2:
@@ -429,31 +543,43 @@ def simulate_lag(trace: jax.Array, *, policy: str,
                 f"active mask has shape {active.shape} but the rates trace "
                 f"has shape {trace.shape}; the mask must name every "
                 f"(step, partition) cell")
+    if crash is not None:
+        check_crash(crash, trace.shape)
+        crash = jnp.asarray(crash)
     return _simulate_jit(trace, jnp.asarray(initial_lag, jnp.float32),
                          policy.upper(), cfg, active,
-                         record_assign=record_assign)
+                         record_assign=record_assign, crash=crash)
 
 
 def _sweep_impl(policies: Tuple[str, ...], traces: jax.Array,
                 cfg: LagSimConfig, active: Optional[jax.Array] = None,
-                valid: Optional[jax.Array] = None) -> LagSweepResult:
+                valid: Optional[jax.Array] = None,
+                crash: Optional[jax.Array] = None) -> LagSweepResult:
     """Unjitted sweep core, shared by the module-level jit below and the
     fleet execution layer (``repro.fleet``), which jits it under its own
     bounded per-bucket cache.  ``valid`` (bool[B, T], fleet-internal)
-    gates sketch/alert updates on padded bucket steps."""
+    gates sketch/alert updates on padded bucket steps.  ``crash``
+    (bool[B, T, 2N + 2]) is a crash schedule per stream (``_simulate``);
+    a run with one takes the per-step scan for every policy."""
     zero0 = jnp.zeros(traces.shape[2], jnp.float32)
     fused_fields = {}
     if cfg.fused_steps:
         # route the heuristic family through the fused multi-step path as
         # ONE family-batched run; everything else keeps the per-step scan
         # (fused_mode raises a named error for fused-incompatible configs)
-        modes = {p: fused_mode(p, cfg, traces.shape[2]) for p in policies}
+        modes = {p: fused_mode(p, cfg, traces.shape[2],
+                               crash=crash is not None) for p in policies}
         group = tuple(p for p in policies if modes[p] == "fused")
         if group:
             fused_fields = sweep_fused(group, traces, cfg, active=active,
                                        valid=valid)
 
     def run_policy(p):
+        if crash is not None:
+            return jax.vmap(
+                lambda tr, ac, va, cr: _simulate(tr, zero0, p, cfg, ac,
+                                                 valid=va, crash=cr))(
+                    traces, active, valid, crash)
         if active is None and valid is None:
             return jax.vmap(lambda tr: _simulate(tr, zero0, p, cfg))(traces)
         if valid is None:
@@ -489,7 +615,7 @@ def _sweep_impl(policies: Tuple[str, ...], traces: jax.Array,
         consumers=stacked.consumers, migrations=stacked.migrations,
         unreadable=stacked.unreadable, policies=policies,
         telemetry=stacked.telemetry, sketch=stacked.sketch,
-        incidents=stacked.incidents)
+        incidents=stacked.incidents, orphaned=stacked.orphaned)
 
 
 @functools.partial(jax.jit, static_argnames=("policies", "cfg"))

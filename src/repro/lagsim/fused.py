@@ -44,6 +44,8 @@ control_plane set      raises :class:`FusedPathError`
 telemetry frames/ring  falls back (O(T) frame recording is
                        unfused-only; sketch/alert aggregates are
                        emitted by the fused path, bit-equal)
+crash schedule         falls back (expulsion and orphan windows
+                       are per-step carry)
 n > 14 partitions      falls back (int32 name-bitmask limit)
 use_kernel=True        falls back (per-step drain-kernel bits
                        differ from the reference drain's fusion)
@@ -90,10 +92,11 @@ def _controlplane_wrapped(spec) -> bool:
     return bool(set(ControlPlaneConfig().knobs()) & set(spec.hyperparams))
 
 
-def fused_mode(policy: str, cfg, n: int) -> str:
+def fused_mode(policy: str, cfg, n: int, crash: bool = False) -> str:
     """Route one policy under ``cfg.fused_steps > 0``: ``"fused"`` or
     ``"unfused"`` (documented fallback).  Raises :class:`FusedPathError`
     for the combinations the fused path refuses (see the module table).
+    ``crash``: the run carries a crash schedule.
     """
     spec = get_spec(policy, backend="jax")
     if spec.family == "optimizer":
@@ -113,6 +116,9 @@ def fused_mode(policy: str, cfg, n: int) -> str:
             f"policy {spec.name!r}; drop fused_steps or use the idealized "
             f"variant of the scaler")
     if spec.family != "heuristic":
+        return "unfused"
+    if crash:
+        # expulsion and orphan windows live in the per-step scan's carry
         return "unfused"
     if n > FUSED_MAX_PARTITIONS:
         return "unfused"

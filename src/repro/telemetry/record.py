@@ -99,7 +99,8 @@ class TelemetryConfig:
             for q in self.lag_quantiles)
 
 
-#: the always-recorded channels (see ``record_step`` for definitions)
+#: the always-recorded channels (see ``record_step`` for definitions); a
+#: run with a crash schedule adds ``orphaned`` after them
 BASE_CHANNELS: Tuple[str, ...] = (
     "consumers",        # replicas billed this step
     "migrations",       # partitions whose owner changed (NEG never counts)
@@ -156,13 +157,15 @@ class TelemetryFrame:
 # ---------------------------------------------------------------------------
 
 def record_step(tele: TelemetryConfig, *, speeds, new_lag, moved, blocked,
-                storm, n_consumers, act_t, capacity, pstate
+                storm, n_consumers, act_t, capacity, pstate, orphaned=None
                 ) -> Tuple[jax.Array, Tuple[str, ...]]:
     """The per-step channel vector ``f32[K]`` and its (static) names.
 
     Pure ``jnp`` on values the engine already computes -- adding the
     recorder never changes the simulated trajectories, only the scan's
-    outputs.  ``storm`` may be ``None`` (no control plane).
+    outputs.  ``storm`` may be ``None`` (no control plane).  ``orphaned``
+    (bool[N], the active partitions a crashed consumer left unread) adds
+    the ``orphaned`` channel; ``None`` (no crash schedule) leaves it out.
     """
     n = speeds.shape[0]
     moved_speed = jnp.sum(jnp.where(moved, speeds, 0.0))
@@ -191,6 +194,9 @@ def record_step(tele: TelemetryConfig, *, speeds, new_lag, moved, blocked,
         # an all-dead step has no distribution; record 0, not NaN
         qs = jnp.where(jnp.isnan(qs), 0.0, qs)
         vals.extend(qs[i] for i in range(len(tele.lag_quantiles)))
+    if orphaned is not None:
+        vals.append(jnp.sum(orphaned.astype(jnp.float32)))
+        names = names + ("orphaned",)
     if isinstance(pstate, CounterState):
         vals.extend(pstate.counters[i].astype(jnp.float32)
                     for i in range(len(pstate.names)))
